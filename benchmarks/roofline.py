@@ -37,6 +37,9 @@ def ensure_results(timeout: float = 600.0) -> None:
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src)
+    # A CPU lowering of the LM stack: the child must not ask for the TPU
+    # this (JAX-importing) parent already holds.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--smoke",
          "--out-dir", os.path.abspath(DRYRUN_DIR)],

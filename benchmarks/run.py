@@ -21,6 +21,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="small datasets only")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         chaos_bench,
         convergence,

@@ -31,6 +31,7 @@ from repro.api.registry import (
     solve,
 )
 from repro.configs.fdsvrg_linear import CONFIGS
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _parser() -> argparse.ArgumentParser:
@@ -94,6 +95,7 @@ def _print_registry() -> None:
 
 def main(argv: list[str] | None = None) -> int:
     args = _parser().parse_args(argv)
+    use_compile_cache()
     if args.list:
         _print_registry()
         return 0
@@ -132,13 +134,13 @@ def main(argv: list[str] | None = None) -> int:
         overrides["q"] = args.workers
     elif info.needs_mesh and lc is not None:
         # shard_map: the worker count IS the mesh size; drop the config's
-        # paper worker count so the default 1-device mesh decides — and
-        # say so, because a q=1 run meters zero communication and is NOT
-        # comparable to the preset's multi-worker runs.
+        # paper worker count so the default mesh over every local device
+        # decides — and say so, because its comm meters reflect that q,
+        # not the preset's multi-worker runs.
         overrides["q"] = None
         import jax
 
-        n_dev = len(jax.devices())
+        n_dev = jax.local_device_count()
         print(f"note: {args.method} runs at the mesh size (q={n_dev} "
               f"device(s) here), not the preset's workers={lc.workers}; "
               "comm meters reflect that q")

@@ -46,6 +46,8 @@ import dataclasses
 import functools
 from typing import Callable
 
+import jax
+
 from repro.api.cache import BLOCK_CACHE
 from repro.api.spec import PAPER, ExperimentSpec
 from repro.core import baselines
@@ -302,7 +304,9 @@ def solve(spec: ExperimentSpec) -> RunResult:
         n = data.num_instances
     mesh = None
     if info.needs_mesh:
-        mesh = spec.mesh if spec.mesh is not None else make_mesh((1,), ("model",))
+        mesh = spec.mesh
+        if mesh is None:  # every local device is one feature-sharded worker
+            mesh = make_mesh((jax.local_device_count(),), ("model",))
         q = int(mesh.devices.size)
         if spec.q is not None and spec.q != q:
             raise ValueError(

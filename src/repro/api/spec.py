@@ -90,7 +90,7 @@ class ExperimentSpec:
     checkpoint_every: int = 1
     resume: bool = False
     # shard_map-only knobs (validated against MethodInfo.needs_mesh):
-    mesh: Any | None = None  # jax Mesh; None -> a 1-device ("model",) mesh
+    mesh: Any | None = None  # jax Mesh; None -> ("model",) over all local devices
     tree_mode: str = "psum"  # "psum" | "butterfly"
 
     def __post_init__(self) -> None:

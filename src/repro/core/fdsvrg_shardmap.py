@@ -113,7 +113,10 @@ def _resolve_backend(
         )
     q = backend.q
     if cfg.dim % q != 0:
-        raise ValueError(f"dim {cfg.dim} must divide by q={q} (pad features)")
+        raise ValueError(
+            f"dim {cfg.dim} must divide by q={q}: the step builders take "
+            "an evenly sharded feature axis (run_fdsvrg_sharded pads it)"
+        )
     return backend, cfg.dim // q
 
 
@@ -305,16 +308,32 @@ def run_fdsvrg_sharded(
     the meter is bit-consistent with the simulation driver's for the same
     shapes (asserted in tests).
 
+    A ``dim`` that q does not divide is padded with zero feature
+    columns up to the next multiple of q.  No row stores them, so their
+    data gradient is zero and their weights stay at their zero start: the
+    objective, the residual and the real coordinates are those of the
+    unpadded problem, and the pad is sliced off the returned ``w``.
+
     Returns a :class:`~repro.core.driver.RunResult` — same schema as
-    every other driver, iterates in the data's dtype.
+    every other driver, iterates in the data's dtype.  Its ``w`` is
+    replicated over the mesh, so host-side code may index it whatever
+    the mesh's axis types (a gather on an Explicit-sharded array needs
+    an output sharding the caller has no reason to know).
     """
     backend = backend or ShardMapBackend(
         mesh=mesh, feature_axes=feature_axes,
         tree_mode=cfg.tree_mode, cluster=cluster,
     )
+    q = backend.q
+    dim = cfg.dim
+    padded = -(-dim // q) * q
+    if padded != dim:
+        cfg = dataclasses.replace(cfg, dim=padded)
+        data = dataclasses.replace(data, dim=padded)
+        if init_w is not None:
+            init_w = jnp.pad(jnp.asarray(init_w), (0, padded - dim))
     fullgrad = make_fullgrad(mesh, cfg, feature_axes, backend=backend)
     inner_epoch = make_inner_epoch(mesh, cfg, feature_axes, backend=backend)
-    q = backend.q
     block_data = BlockCSR.from_padded(data, balanced(cfg.dim, q))
     bidx, bval = block_data.stacked()
     loss = losses_lib.LOSSES[cfg.loss_name]
@@ -336,7 +355,7 @@ def run_fdsvrg_sharded(
         )
         return w
 
-    return run_outer_loop(
+    result = run_outer_loop(
         outer_iters=outer_iters,
         seed=seed,
         init_w=resolve_init_w(init_w, cfg.dim, data.values.dtype),
@@ -345,6 +364,8 @@ def run_fdsvrg_sharded(
         evaluate=make_same_iterate_eval(data.labels, loss, reg, cfg.eta),
         backend=backend,
     )
+    w = jax.device_put(result.w, NamedSharding(mesh, P()))
+    return dataclasses.replace(result, w=w[:dim])
 
 
 def input_shardings(mesh: Mesh, feature_axes: Sequence[str] = ("data", "model")):

@@ -18,8 +18,8 @@ models crossing) a worker boundary:
   shard_map realization over a mesh axis.
 * :mod:`repro.dist.metering`     — ``CommReport``, the per-method
   communication report benchmarks consume.
-* :mod:`repro.dist.compat`       — version-portable wrappers for the jax
-  APIs (``shard_map``, ``make_mesh``) that moved between jax releases.
+* :mod:`repro.dist.compat`       — the one place the repo calls
+  ``jax.shard_map`` and ``jax.make_mesh`` (Auto mesh axes).
 * :mod:`repro.dist.faults`       — seeded fault injection at the
   collective boundary: ``FaultPlan`` / ``RetryPolicy`` /
   ``FaultyBackend``, with retransmissions metered under the ``retry``

@@ -1,12 +1,12 @@
-"""Version-portable wrappers for jax distributed APIs.
+"""Thin wrappers over the jax distributed APIs the repo builds on.
 
-The repo targets the current jax while staying runnable on the 0.4.x
-series baked into the container:
-
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, renaming ``check_rep`` to ``check_vma`` on the way.
-* ``jax.make_mesh`` grew an ``axis_types`` kwarg (with
-  ``jax.sharding.AxisType``) that older releases reject.
+* ``shard_map`` normalizes the replication check flag (``check_vma``)
+  behind one keyword.
+* ``make_mesh`` asks for Auto axis types.  A plain ``jax.make_mesh``
+  mesh has Explicit axes, whose arrays carry their sharding in their
+  type, so every gather on a sharded array then needs an explicit output
+  sharding; the drivers' shard_map programs state their shardings in
+  full and want the compiler to place everything else.
 
 Everything in the repo goes through these two wrappers instead of the
 raw APIs.
@@ -18,27 +18,15 @@ from typing import Any, Sequence
 
 import jax
 
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map
-
-    _CHECK_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KWARG = "check_rep"
-
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` with the replication/VMA check flag normalized."""
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KWARG: check},
+    """``jax.shard_map`` with the replication (VMA) check off by default."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], **kwargs: Any):
-    """``jax.make_mesh`` requesting Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and "axis_types" not in kwargs:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
+    """``jax.make_mesh`` with Auto axis types unless the caller names some."""
+    kwargs.setdefault("axis_types", (jax.sharding.AxisType.Auto,) * len(axes))
     return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)

@@ -26,7 +26,7 @@ from repro.kernels.lazy_update import (
 )
 from repro.kernels.logistic_grad import logistic_grad
 from repro.kernels.prox_update import prox_update
-from repro.kernels.sparse_margin import sparse_margin
+from repro.kernels.sparse_margin import LANES, sparse_margin
 from repro.kernels.svrg_update import svrg_update
 
 
@@ -44,6 +44,32 @@ def _pad_to(x: jax.Array, axis: int, multiple: int, value=0.0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+# SMEM (1 MiB on a v5e) holds a double-buffered window of a row tile's
+# ids: 32768 int32 entries per buffer leaves most of it free.
+_SMEM_ID_WINDOW = 1 << 15
+# Rows of a lane-major vector per grid step of the update kernel: 1 MiB
+# of f32 per block, so w, z and the output chunk, double-buffered, stay
+# well inside the default scoped VMEM.
+_UPDATE_CHUNK_ROWS = 2048
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _lane_major(v: jax.Array, rows: int) -> jax.Array:
+    """[d] -> [rows, 128], zero-padded: feature j at (j // 128, j % 128)."""
+    return jnp.pad(v, (0, rows * LANES - v.shape[0])).reshape(rows, LANES)
+
+
+def margin_row_tile(n: int, width: int) -> int:
+    """Row tile of :func:`sparse_margins`: as many rows as the SMEM id
+    window holds at this width (a multiple of 8), never more than the
+    call has."""
+    cap = max(8, (_SMEM_ID_WINDOW // max(width, 1)) // 8 * 8)
+    return min(_round_up(max(n, 1), 8), cap)
+
+
 def sparse_margins(
     indices: jax.Array,  # int32[N, nnz_l], block-LOCAL ids (BlockCSR rows)
     values: jax.Array,  # [N, nnz_l]
@@ -51,20 +77,34 @@ def sparse_margins(
     *,
     block_rows: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:  # [N] float32
+    scalar_loops: bool | None = None,
+) -> jax.Array:  # [N]
     """Fused gather-margin over one block's local CSR rows (the FD-SVRG
-    margin hot path).  ``block_rows=None`` keeps all rows in one tile,
-    which is also the shape the bit-identity contract is stated for."""
+    margin hot path).  On the TPU the gather runs as the kernel's scalar
+    loop, the nnz axis is padded to the lane width with (id 0, value 0)
+    entries, and ``block_rows=None`` derives the row tile from the shapes
+    (:func:`margin_row_tile`).  The interpreter runs the jnp-gather body
+    at the caller's width with all rows in one tile — the shape the
+    bit-identity contract is stated for, since XLA:CPU picks a row
+    reduction's order by shape; ``scalar_loops=True`` runs the TPU body
+    under it instead."""
     interpret = _interpret_default() if interpret is None else interpret
+    scalar_loops = not interpret if scalar_loops is None else scalar_loops
     n = indices.shape[0]
+    if not interpret:
+        indices = _pad_to(indices, 1, LANES)
+        values = _pad_to(values, 1, LANES)
     if block_rows is None:
-        block_rows = max(n, 1)
+        width = indices.shape[1]
+        block_rows = margin_row_tile(n, width) if scalar_loops else max(n, 1)
     idx2 = _pad_to(indices, 0, block_rows)
     val2 = _pad_to(values, 0, block_rows)
+    w_rows = _round_up(max(-(-w_block.shape[0] // LANES), 1), 8)
     out = sparse_margin(
-        w_block[None, :], idx2, val2, block_rows=block_rows, interpret=interpret
+        _lane_major(w_block, w_rows), idx2, val2, block_rows=block_rows,
+        scalar_loops=scalar_loops, interpret=interpret,
     )
-    return out[0, :n]
+    return out[:n]
 
 
 def fused_block_update(
@@ -108,27 +148,52 @@ def fused_block_prox_update(
     lam1: float = 0.0,  # L1 strength handled by the fused prox
     lam2: float = 0.0,  # elastic-net L2 strength handled by the fused prox
     interpret: bool | None = None,
+    scalar_loops: bool | None = None,
 ) -> jax.Array:  # [d_block]
     """Fused scatter-grad + proximal variance-reduced update on one block:
     prox_{eta*g}(w - eta * (scatter(coef * x) + z + lam * w)) in a single
     pass.  Covers the whole regularizer family — lam1 = lam2 = 0 elides
     the prox stages, reproducing :func:`fused_block_update` bit-exactly;
-    the prox is elementwise (paper eq. 3), so it stays block-local."""
+    the prox is elementwise (paper eq. 3), so it stays block-local.  On
+    the TPU the scatter runs as the kernel's scalar loop over chunks of
+    the block; the interpreter runs the ``.at[].add`` body in one chunk
+    unless ``scalar_loops=True`` asks for the TPU body."""
     interpret = _interpret_default() if interpret is None else interpret
+    scalar_loops = not interpret if scalar_loops is None else scalar_loops
     d = w_block.shape[0]
+    rows = max(-(-d // LANES), 1)
+    chunks = -(-rows // _UPDATE_CHUNK_ROWS) if scalar_loops else 1
+    chunk_rows = _round_up(-(-rows // chunks), 8)
+    rows = chunk_rows * chunks
     out = prox_update(
-        w_block[None, :],
+        _lane_major(w_block, rows),
         indices,
         values,
-        coef[None, :],
-        z_block[None, :],
-        jnp.asarray(eta, dtype=w_block.dtype)[None, None],
+        coef,
+        _lane_major(z_block, rows),
+        jnp.asarray(eta, dtype=w_block.dtype).reshape(1),
+        chunk_rows=chunk_rows,
         lam=lam,
         lam1=lam1,
         lam2=lam2,
+        scalar_loops=scalar_loops,
         interpret=interpret,
     )
-    return out[0, :d]
+    return out.reshape(-1)[:d]
+
+
+def _require_interpret(kernel: str, interpret: bool) -> None:
+    """Three lazy kernels gather and scatter with jnp indexing, which
+    Mosaic cannot lower; compiled for a TPU they fail loudly here instead
+    of at an opaque lowering error (or a silent fallback).  The flush is
+    elementwise over the block and does lower."""
+    if not interpret:
+        raise NotImplementedError(
+            f"{kernel}: this Pallas kernel has no TPU lowering (its "
+            "gather/scatter needs the scalar-loop rewrite the margin and "
+            "prox kernels have); run lazy_updates with use_kernels=False "
+            "on a TPU, or interpret=True on CPU"
+        )
 
 
 def _i32_scalar(x) -> jax.Array:
@@ -153,6 +218,7 @@ def lazy_block_catchup(
     touched at inner step ``m`` (see :mod:`repro.kernels.lazy_update`),
     returning the caught-up block and the updated ``last`` counters."""
     interpret = _interpret_default() if interpret is None else interpret
+    _require_interpret("lazy_block_catchup", interpret)
     d = w_block.shape[0]
     w_out, last_out = lazy_catchup(
         w_block[None, :],
@@ -186,6 +252,7 @@ def lazy_block_touch_update(
     """Exact-lazy eager half-step: the dense prox update evaluated only at
     the touched lanes — O(u * nnz_l) instead of O(d_block)."""
     interpret = _interpret_default() if interpret is None else interpret
+    _require_interpret("lazy_block_touch_update", interpret)
     d = w_block.shape[0]
     out = lazy_touch_update(
         w_block[None, :],
@@ -251,6 +318,7 @@ def lazy_block_proba_update(
     """Probabilistic lazy step: touched features only, decay scaled by the
     per-feature corrections so the expected update is unbiased."""
     interpret = _interpret_default() if interpret is None else interpret
+    _require_interpret("lazy_block_proba_update", interpret)
     d = w_block.shape[0]
     out = lazy_proba_update(
         w_block[None, :],

@@ -4,8 +4,7 @@
 touches jax device state; callers (dryrun.py) set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import.  Mesh construction goes through :func:`repro.dist.compat.make_mesh`
-so it works across jax releases (the ``axis_types`` kwarg is newer than
-the 0.4.x series).
+(Auto axis types).
 """
 
 from __future__ import annotations

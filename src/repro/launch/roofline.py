@@ -124,8 +124,6 @@ def from_compiled(compiled, chips: int) -> Roofline:
     *per-device* partitioned module; totals are per-device x chips.
     """
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

@@ -50,6 +50,7 @@ from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, run_fdsvrg_sharded
 from repro.core.partition import balanced
 from repro.data.sparse import PaddedCSR
 from repro.data.synthetic import make_sparse_classification
+from repro.dist import make_mesh
 
 LOSS = losses.logistic
 REG = losses.l2(1e-3)
@@ -96,7 +97,7 @@ def test_solve_matches_direct_driver(data, method):
             data, balanced(data.dim, q), LOSS, REG, cfg
         )
     elif method == "fdsvrg_sharded":
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         shcfg = FDSVRGShardedConfig(
             dim=data.dim, num_instances=data.num_instances,
             nnz_max=data.nnz_max, eta=eta, inner_steps=inner, batch_size=u,
@@ -257,7 +258,7 @@ def test_option_ii_rejected_where_ignored(data):
 
 
 def test_mesh_rejected_for_non_shardmap_methods(data):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="mesh"):
         solve(ExperimentSpec(method="serial", data=data, mesh=mesh))
 

@@ -38,7 +38,7 @@ from repro.core.fdsvrg import (
 )
 from repro.core.partition import balanced
 from repro.data.synthetic import make_sparse_classification
-from repro.dist import COSTS, ClusterModel
+from repro.dist import COSTS, ClusterModel, make_mesh
 
 LOSS = losses.logistic
 REG = losses.l2(1e-3)
@@ -202,12 +202,10 @@ def test_worker_simulation_meters_like_the_jitted_driver(data):
 def test_sharded_driver_modeled_time_matches_cost_model(data):
     """run_fdsvrg_sharded charges COSTS too (q=1 mesh: zero scalars, pure
     compute closed form)."""
-    import jax
-
     from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, run_fdsvrg_sharded
 
     outers, m, u = 2, 8, 2
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=0.2, inner_steps=m, batch_size=u, lam=1e-3,
@@ -297,12 +295,10 @@ def test_async_grad_norm_recorded_at_post_epoch_iterate(data, runner):
 def test_history_schema_uniform_across_all_drivers(data):
     """Every driver emits the same OuterRecord schema with finite
     objectives — the shard_map driver included (no more bare tuples)."""
-    import jax
-
     from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, run_fdsvrg_sharded
 
     cfg = SVRGConfig(eta=0.1, inner_steps=8, outer_iters=2, seed=1)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     sh_cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=0.1, inner_steps=8, batch_size=1, lam=1e-3,

@@ -333,9 +333,7 @@ def test_linear_convergence_rate(small_data):
     bound = a**M + b / (1 - a)
     assert bound < 1.0
 
-    # jax.enable_x64 graduated from jax.experimental after the 0.4 series
-    enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-    with enable_x64(True):
+    with jax.enable_x64(True):
         data64 = PaddedCSR(
             indices=jnp.asarray(np.asarray(small_data.indices)),
             values=jnp.asarray(np.asarray(small_data.values), dtype=jnp.float64),

@@ -30,7 +30,7 @@ from repro.core.fdsvrg_shardmap import (
 from repro.core.partition import balanced
 from repro.data.block_csr import BlockCSR
 from repro.data.synthetic import make_sparse_classification
-from repro.dist import SimBackend
+from repro.dist import SimBackend, make_mesh
 
 
 def _stacked(data, q):
@@ -42,7 +42,7 @@ def test_shardmap_single_device_matches_serial():
         dim=512, num_instances=64, nnz_per_instance=8, seed=0
     )
     eta, inner, outers, u, lam = 0.2, 16, 3, 2, 1e-3
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=eta, inner_steps=inner, batch_size=u, lam=lam,
@@ -84,7 +84,7 @@ def test_shardmap_butterfly_mode_single_device():
     data = make_sparse_classification(
         dim=256, num_instances=32, nnz_per_instance=8, seed=1
     )
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=0.1, inner_steps=8, batch_size=1, tree_mode="butterfly",
@@ -105,7 +105,7 @@ def test_shardmap_use_kernels_bit_identical_single_device():
     data = make_sparse_classification(
         dim=384, num_instances=48, nnz_per_instance=8, seed=3
     )
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     samples = np.random.default_rng(5).integers(
         0, data.num_instances, size=(12, 2)
     ).astype(np.int32)
@@ -136,7 +136,7 @@ def test_sharded_driver_metering_matches_simulation_driver():
         dim=512, num_instances=64, nnz_per_instance=8, seed=0
     )
     inner, u, outers = 8, 4, 2
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=0.1, inner_steps=inner, batch_size=u, lam=1e-3,
@@ -176,7 +176,7 @@ def test_sharded_driver_matches_sim_driver_iterates_and_objective():
         dim=384, num_instances=48, nnz_per_instance=8, seed=1
     )
     inner, u, outers = 10, 2, 2
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=0.2, inner_steps=inner, batch_size=u, lam=1e-3,
@@ -206,6 +206,8 @@ def test_sharded_driver_gnorm_is_post_epoch_residual():
     data = make_sparse_classification(
         dim=256, num_instances=32, nnz_per_instance=8, seed=2
     )
+    # A plain jax.make_mesh mesh on purpose: its axes are Explicit, and the
+    # host-side full_gradient below must still index the returned w.
     mesh = jax.make_mesh((1,), ("model",))
     for reg_name, lam, lam2 in (("l2", 1e-3, 0.0), ("l1", 2e-3, 0.0)):
         cfg = FDSVRGShardedConfig(
@@ -233,15 +235,14 @@ def test_sharded_driver_preserves_float64():
     data32 = make_sparse_classification(
         dim=128, num_instances=16, nnz_per_instance=4, seed=0
     )
-    enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-    with enable_x64(True):
+    with jax.enable_x64(True):
         data = PaddedCSR(
             indices=jnp.asarray(np.asarray(data32.indices)),
             values=jnp.asarray(np.asarray(data32.values), dtype=jnp.float64),
             labels=jnp.asarray(np.asarray(data32.labels), dtype=jnp.float64),
             dim=data32.dim,
         )
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         cfg = FDSVRGShardedConfig(
             dim=data.dim, num_instances=data.num_instances,
             nnz_max=data.nnz_max, eta=0.2, inner_steps=4, batch_size=2,
@@ -256,7 +257,7 @@ def test_sharded_driver_preserves_float64():
 
 
 def test_input_shardings_match_step_arity():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     shardings = input_shardings(mesh, feature_axes=("model",))
     assert len(shardings) == 5  # w, block_indices, block_values, labels, samples
 
@@ -272,11 +273,12 @@ _SUBPROCESS_PROG = textwrap.dedent(
     from repro.core.partition import balanced
     from repro.data.block_csr import BlockCSR
     from repro.data.synthetic import make_sparse_classification
+    from repro.dist import make_mesh
 
     assert jax.device_count() == 8
     data = make_sparse_classification(dim=512, num_instances=48, nnz_per_instance=8, seed=0)
     eta, inner, outers, u, lam = 0.2, 12, 2, 2, 1e-3
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     cfg = FDSVRGShardedConfig(dim=data.dim, num_instances=data.num_instances,
                               nnz_max=data.nnz_max, eta=eta, inner_steps=inner,
                               batch_size=u, lam=lam, tree_mode="{mode}")
@@ -319,3 +321,50 @@ def test_shardmap_eight_devices_subprocess(mode):
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "OK-8DEV" in proc.stdout
+
+
+_FOUR_DEVICE_PROG = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.api import ExperimentSpec, solve
+    from repro.core import losses
+    from repro.data.synthetic import make_sparse_classification
+    from repro.dist import make_mesh
+
+    assert jax.device_count() == 4
+    # d = 513 is odd, as every Table-1 width is: the sharded driver pads it
+    # to 516 zero-extended features and slices the pad back off.
+    data = make_sparse_classification(dim=513, num_instances=40, nnz_per_instance=8, seed=1)
+    common = dict(data=data, eta=0.2, inner_steps=10, outer_iters=2, batch_size=2,
+                  reg=losses.l2(1e-3), seed=4)
+    fd = solve(ExperimentSpec(method="fdsvrg", q=4, **common))
+    for mesh in (make_mesh((4,), ("model",)), None):  # None: solve's default
+        sh = solve(ExperimentSpec(method="fdsvrg_sharded", mesh=mesh, **common))
+        assert sh.w.shape == (513,), sh.w.shape
+        assert len(sh.w.sharding.device_set) == 4, sh.w.sharding
+        np.testing.assert_allclose(np.asarray(sh.w), np.asarray(fd.w), rtol=2e-4, atol=2e-6)
+        for a, b in zip(sh.history, fd.history):
+            np.testing.assert_allclose(a.objective, b.objective, rtol=1e-5)
+            assert a.comm_scalars == b.comm_scalars
+    print("OK-4DEV")
+    """
+)
+
+
+def test_sharded_driver_pads_odd_dim_on_four_devices_subprocess():
+    """On 4 devices an odd d is padded to a multiple of q and the result
+    matches fdsvrg at q=4 on the same sample stream — both on an explicit
+    4-device mesh and on the mesh solve() builds when given none, which
+    must span all 4 local devices."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src")
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICE_PROG],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "OK-4DEV" in proc.stdout

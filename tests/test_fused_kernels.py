@@ -69,6 +69,36 @@ def test_sparse_margin_zero_value_padding_is_inert():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
+# The TPU body (scalar-loop gather over lane-major w) under the interpreter:
+# its gather is exact, and its margins agree with the reference to float32
+# rounding — XLA:CPU may contract the reference's fused gather-reduce into
+# multiply-adds that the loop body's reduction of a written-out tile skips.
+
+
+@pytest.mark.parametrize("d,n,nnz,block_rows", [
+    (300, 37, 9, 8), (1000, 20, 5, None), (129, 3, 130, None),
+])
+def test_sparse_margin_scalar_loop_gather_is_exact(d, n, nnz, block_rows):
+    w, idx, _ = _case(d, n, nnz, seed=d + n)
+    for k in (0, nnz - 1):
+        one_hot = jnp.zeros((n, nnz), jnp.float32).at[:, k].set(1.0)
+        got = ops.sparse_margins(
+            idx, one_hot, w, block_rows=block_rows, interpret=True,
+            scalar_loops=True,
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(w[idx[:, k]]))
+
+
+@pytest.mark.parametrize("d,n,nnz", [(64, 8, 4), (300, 37, 9), (1024, 40, 16)])
+def test_sparse_margin_scalar_loops_match_ref(d, n, nnz):
+    w, idx, val = _case(d, n, nnz, seed=d)
+    got = ops.sparse_margins(idx, val, w, interpret=True, scalar_loops=True)
+    want = jax.jit(ref.sparse_margin_ref)(w, idx, val)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6
+    )
+
+
 # ---------------------------------------------------------------------------
 # fused_update
 # ---------------------------------------------------------------------------
@@ -152,6 +182,54 @@ def test_prox_update_matches_ref_bitwise(d, u, nnz, lam, lam1, lam2):
         ref.prox_update_ref, static_argnames=("lam", "lam1", "lam2")
     )(w, idx, val, coef, z, eta, lam=lam, lam1=lam1, lam2=lam2)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("chunk_rows", [8, 16, 48])
+@pytest.mark.parametrize(
+    "lam,lam1,lam2", [(1e-4, 0.0, 0.0), (0.0, 1e-2, 1e-3)]
+)
+def test_prox_update_scalar_loops_match_ref(chunk_rows, lam, lam1, lam2):
+    """The TPU body — the scatter as a scalar loop over row chunks of the
+    lane-major block — under the interpreter, against the reference to
+    float32 rounding (it adds the scattered terms onto z + lam * w, the
+    association XLA picks for the reference only by context).  d = 6000
+    spans 47 lane rows, so every chunking leaves ids in several chunks."""
+    from repro.kernels.prox_update import prox_update
+
+    d, u, nnz = 6000, 4, 12
+    w, idx, val = _case(d, u, nnz, seed=7)
+    idx = idx.at[0, :3].set(idx[1, 0])  # duplicate ids accumulate in order
+    coef = jnp.asarray(RNG.normal(size=u).astype(np.float32))
+    z = jnp.asarray(RNG.normal(size=d).astype(np.float32))
+    eta = jnp.float32(0.2)
+    rows = -(-47 // chunk_rows) * chunk_rows
+    lane_major = lambda v: jnp.pad(v, (0, rows * 128 - d)).reshape(rows, 128)
+    got = prox_update(
+        lane_major(w), idx, val, coef, lane_major(z), eta.reshape(1),
+        chunk_rows=chunk_rows, lam=lam, lam1=lam1, lam2=lam2, interpret=True,
+    ).reshape(-1)[:d]
+    want = jax.jit(
+        ref.prox_update_ref, static_argnames=("lam", "lam1", "lam2")
+    )(w, idx, val, coef, z, eta, lam=lam, lam1=lam1, lam2=lam2)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7
+    )
+
+
+def test_prox_update_wrapper_scalar_loops_match_ref():
+    w, idx, val = _case(300, 5, 9, seed=3)
+    coef = jnp.asarray(RNG.normal(size=5).astype(np.float32))
+    z = jnp.asarray(RNG.normal(size=300).astype(np.float32))
+    eta = jnp.float32(0.1)
+    got = ops.fused_block_prox_update(
+        w, idx, val, coef, z, eta, lam=1e-3, interpret=True, scalar_loops=True
+    )
+    want = ref.prox_update_ref(
+        w, idx, val, coef, z, eta, lam=1e-3, lam1=0.0, lam2=0.0
+    )
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-7
+    )
 
 
 def test_prox_update_l2_path_reproduces_fused_update():

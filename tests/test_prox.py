@@ -30,6 +30,7 @@ from repro.core.fdsvrg import (
 from repro.core import baselines
 from repro.core.partition import balanced
 from repro.data.synthetic import make_sparse_classification
+from repro.dist import make_mesh
 
 try:
     import hypothesis  # noqa: F401  (dev-only dep; see requirements-dev.txt)
@@ -226,7 +227,7 @@ def test_prox_shardmap_matches_serial_reference(reg_name, lam, lam2, use_kernels
         dim=384, num_instances=48, nnz_per_instance=8, seed=3
     )
     eta, inner, outers, u = 0.2, 12, 2, 2
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     cfg = FDSVRGShardedConfig(
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=eta, inner_steps=inner, batch_size=u,
