@@ -48,6 +48,7 @@ from typing import Callable
 
 import jax
 
+from repro import obs
 from repro.api.cache import BLOCK_CACHE
 from repro.api.spec import PAPER, ExperimentSpec
 from repro.core import baselines
@@ -289,6 +290,13 @@ def solve(spec: ExperimentSpec) -> RunResult:
     iterate, per-outer history (objective, optimality residual, metered
     communication, modeled and wall-clock time), and the run's meter.
     """
+    # solve.prepare covers everything before the outer loop starts, in
+    # here and in the adapters; run_outer_loop closes it.
+    with obs.span("solve"), obs.span("solve.prepare"):
+        return _solve(spec)
+
+
+def _solve(spec: ExperimentSpec) -> RunResult:
     info = method_info(spec.method)
     _validate(spec, info)
     if spec.source is not None:
@@ -449,10 +457,10 @@ def _solve_fdsvrg_sim(spec, data, p, mesh) -> RunResult:
 @register_method(
     "fdsvrg_sharded", backend="shardmap",
     # The shard_map worker has a kernel path, but solve() does not expose
-    # it yet: Pallas-inside-shard_map is only exercised by the dedicated
-    # perf harness (launch/perf), not certified through this front door —
-    # so the honest capability today is False, and asking for it errors
-    # instead of silently running the jnp path.
+    # it: nothing runs Pallas inside shard_map on a chip (one CPU test
+    # checks it in interpret mode on a one-device mesh), so the honest
+    # capability is False, and asking for it errors instead of silently
+    # running the jnp path.
     supports_kernels=False,
     supports_option_ii=False,  # the sharded inner scan has no step mask
     needs_mesh=True,

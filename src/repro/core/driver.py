@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import ckpt
 from repro.core import losses as losses_lib
 from repro.dist import Collectives, CommMeter, FaultError
@@ -349,7 +350,14 @@ def run_outer_loop(
     smaller step); hooks that don't accept it still get abort/retry.
     ``checkpoint`` arms persistence/resume (see
     :class:`CheckpointPolicy`).
+
+    Under the profiler each phase is a :mod:`repro.obs` span:
+    ``loop.snapshot0``, then per attempt an ``outer`` step span
+    (``step_num=t``, ``attempt``) holding ``outer.snapshot``,
+    ``outer.evaluate`` and ``outer.checkpoint``; the epoch hooks add
+    ``outer.samples`` and ``outer.epoch``.
     """
+    obs.end("solve.prepare")
     rng = np.random.default_rng(seed)
     w = init_w
     meter = backend.meter if backend is not None else CommMeter()
@@ -358,7 +366,8 @@ def run_outer_loop(
     start_outer = 0
     accepts_scale = "eta_scale" in inspect.signature(epoch).parameters
     t_start = time.perf_counter()
-    z_data, s0 = snapshot(w)  # outer-0 snapshot
+    with obs.span("loop.snapshot0"):
+        z_data, s0 = snapshot(w)  # outer-0 snapshot
     if checkpoint is not None and checkpoint.resume and checkpoint.exists():
         state = ckpt.restore(
             checkpoint.path, {"w": w, "z": z_data, "s0": s0}
@@ -381,74 +390,81 @@ def run_outer_loop(
     for t in range(start_outer, outer_iters):
         attempts = 0
         while True:
-            begin_outer = getattr(backend, "begin_outer", None)
-            if begin_outer is not None:
-                begin_outer(t)
-            try:
-                if accepts_scale:
-                    w_new = epoch(t, rng, w, z_data, s0, eta_scale=eta_scale)
-                else:
-                    w_new = epoch(t, rng, w, z_data, s0)
-                # Rotation: the post-epoch full gradient is next outer's
-                # snapshot and this record's diagnostic pair (z and w at
-                # the SAME iterate).
-                z_new, s0_new = snapshot(w_new)
-                obj, gnorm = evaluate(w_new, z_new, s0_new)
-                if recovery is not None:
-                    floor = max(abs(prev_obj), 1.0) if prev_obj is not None \
-                        else None
-                    if not (np.isfinite(obj) and np.isfinite(gnorm)):
-                        raise DivergenceError(
-                            f"outer {t}: non-finite objective/optimality "
-                            f"(obj={obj}, norm={gnorm})"
+            with obs.span("outer", step_num=t, attempt=attempts):
+                begin_outer = getattr(backend, "begin_outer", None)
+                if begin_outer is not None:
+                    begin_outer(t)
+                try:
+                    if accepts_scale:
+                        w_new = epoch(t, rng, w, z_data, s0, eta_scale=eta_scale)
+                    else:
+                        w_new = epoch(t, rng, w, z_data, s0)
+                    # Rotation: the post-epoch full gradient is next outer's
+                    # snapshot and this record's diagnostic pair (z and w at
+                    # the SAME iterate).
+                    with obs.span("outer.snapshot"):
+                        z_new, s0_new = snapshot(w_new)
+                    with obs.span("outer.evaluate"):
+                        obj, gnorm = evaluate(w_new, z_new, s0_new)
+                    if recovery is not None:
+                        floor = max(abs(prev_obj), 1.0) if prev_obj is not None \
+                            else None
+                        if not (np.isfinite(obj) and np.isfinite(gnorm)):
+                            raise DivergenceError(
+                                f"outer {t}: non-finite objective/optimality "
+                                f"(obj={obj}, norm={gnorm})"
+                            )
+                        if floor is not None and \
+                                obj > recovery.divergence_factor * floor:
+                            raise DivergenceError(
+                                f"outer {t}: objective exploded "
+                                f"({obj:.3e} > {recovery.divergence_factor:g} * "
+                                f"{floor:.3e})"
+                            )
+                except FaultError as err:
+                    if recovery is None or attempts >= recovery.max_epoch_retries:
+                        raise
+                    attempts += 1
+                    if isinstance(err, DivergenceError):
+                        eta_scale *= recovery.eta_backoff
+                    if recovery.on_abort is not None and backend is not None:
+                        recovery.on_abort(backend)
+                    # Retry from the snapshot: w/z_data/s0 were never
+                    # rotated, so the failed epoch leaves no trace in the
+                    # trajectory — only in the meter (retries, aborts) and
+                    # modeled time.
+                    continue
+                w, z_data, s0 = w_new, z_new, s0_new
+                prev_obj = obj
+                history.append(
+                    OuterRecord(
+                        t,
+                        obj,
+                        gnorm,
+                        meter.total_scalars,
+                        meter.total_rounds,
+                        backend.modeled_time_s if backend is not None else 0.0,
+                        time.perf_counter() - t_start,
+                    )
+                )
+                if checkpoint is not None and (
+                    (t + 1) % checkpoint.every == 0 or t == outer_iters - 1
+                ):
+                    with obs.span("outer.checkpoint"):
+                        _save_outer_state(
+                            checkpoint,
+                            w=w,
+                            z_data=z_data,
+                            s0=s0,
+                            outer_next=t + 1,
+                            eta_scale=eta_scale,
+                            rng=rng,
+                            meter=meter,
+                            modeled_time_s=(
+                                backend.modeled_time_s
+                                if backend is not None else 0.0
+                            ),
+                            history=history,
                         )
-                    if floor is not None and \
-                            obj > recovery.divergence_factor * floor:
-                        raise DivergenceError(
-                            f"outer {t}: objective exploded "
-                            f"({obj:.3e} > {recovery.divergence_factor:g} * "
-                            f"{floor:.3e})"
-                        )
-                break
-            except FaultError as err:
-                if recovery is None or attempts >= recovery.max_epoch_retries:
-                    raise
-                attempts += 1
-                if isinstance(err, DivergenceError):
-                    eta_scale *= recovery.eta_backoff
-                if recovery.on_abort is not None and backend is not None:
-                    recovery.on_abort(backend)
-                # Retry from the snapshot: w/z_data/s0 were never rotated,
-                # so the failed epoch leaves no trace in the trajectory —
-                # only in the meter (retries, aborts) and modeled time.
-        w, z_data, s0 = w_new, z_new, s0_new
-        prev_obj = obj
-        history.append(
-            OuterRecord(
-                t,
-                obj,
-                gnorm,
-                meter.total_scalars,
-                meter.total_rounds,
-                backend.modeled_time_s if backend is not None else 0.0,
-                time.perf_counter() - t_start,
-            )
-        )
-        if checkpoint is not None and (
-            (t + 1) % checkpoint.every == 0 or t == outer_iters - 1
-        ):
-            _save_outer_state(
-                checkpoint,
-                w=w,
-                z_data=z_data,
-                s0=s0,
-                outer_next=t + 1,
-                eta_scale=eta_scale,
-                rng=rng,
-                meter=meter,
-                modeled_time_s=(
-                    backend.modeled_time_s if backend is not None else 0.0
-                ),
-                history=history,
-            )
+            break
     return RunResult(w=w, history=history, meter=meter)

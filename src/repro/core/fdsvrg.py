@@ -156,21 +156,24 @@ def _full_grad_blocks(
     loss = losses_lib.LOSSES[loss_name]
     q = len(block_dims)
     bounds = _bounds(block_dims)
-    parts = [
-        _block_margins(
-            block_indices[l],
-            block_values[l],
-            jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
-            use_kernels,
-        )
-        for l in range(q)
-    ]
-    s0 = tree_order_sum(parts)
-    coeffs = loss.dvalue(s0, labels) / labels.shape[0]
-    z_blocks = [
-        local_scatter(block_indices[l], block_values[l], coeffs, block_dims[l])
-        for l in range(q)
-    ]
+    with jax.named_scope("full_grad/margins"):
+        parts = [
+            _block_margins(
+                block_indices[l],
+                block_values[l],
+                jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
+                use_kernels,
+            )
+            for l in range(q)
+        ]
+        s0 = tree_order_sum(parts)
+    with jax.named_scope("full_grad/scatter"):
+        coeffs = loss.dvalue(s0, labels) / labels.shape[0]
+        z_blocks = [
+            local_scatter(block_indices[l], block_values[l], coeffs,
+                          block_dims[l])
+            for l in range(q)
+        ]
     z_data = jnp.concatenate(z_blocks) if q > 1 else z_blocks[0]
     return z_data, s0
 
@@ -274,21 +277,24 @@ def _inner_epoch(
 
     def step(w, inp):
         ids, mask = inp  # ids: int32[u]
-        y = labels[ids]
-        rows = [(block_indices[l][ids], block_values[l][ids]) for l in range(q)]
-        parts = [
-            _block_margins(
-                rows[l][0],
-                rows[l][1],
-                jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
-                use_kernels,
-            )
-            for l in range(q)
-        ]
-        # Pairwise summation mirroring Figure 5 exactly (shared with the
-        # simulation and interpret backends, so floating point matches).
-        s_m = tree_order_sum(parts)
-        s_anchor = s0[ids]
+        with jax.named_scope("inner/gather"):
+            y = labels[ids]
+            rows = [(block_indices[l][ids], block_values[l][ids])
+                    for l in range(q)]
+            parts = [
+                _block_margins(
+                    rows[l][0],
+                    rows[l][1],
+                    jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
+                    use_kernels,
+                )
+                for l in range(q)
+            ]
+            # Pairwise summation mirroring Figure 5 exactly (shared with
+            # the simulation and interpret backends, so floating point
+            # matches).
+            s_m = tree_order_sum(parts)
+            s_anchor = s0[ids]
         coef = (loss.dvalue(s_m, y) - loss.dvalue(s_anchor, y)) / u
         eta_m = eta * mask
         new_blocks = []
@@ -298,16 +304,20 @@ def _inner_epoch(
             z_blk = jax.lax.slice_in_dim(z_data, bounds[l], bounds[l + 1])
             if use_kernels:
                 k_lam, k_l1, k_l2 = kernel_lams
-                new_blocks.append(
-                    ops.fused_block_prox_update(
-                        w_blk, idx, val, coef, z_blk, eta_m,
-                        lam=k_lam, lam1=k_l1, lam2=k_l2,
+                # The fused kernel scatters inside its update.
+                with jax.named_scope("inner/update"):
+                    new_blocks.append(
+                        ops.fused_block_prox_update(
+                            w_blk, idx, val, coef, z_blk, eta_m,
+                            lam=k_lam, lam1=k_l1, lam2=k_l2,
+                        )
                     )
-                )
             else:
-                g = local_scatter(idx, val, coef, block_dims[l])
-                g = g + z_blk + reg.smooth_grad(w_blk)
-                new_blocks.append(reg.prox(w_blk - eta_m * g, eta_m))
+                with jax.named_scope("inner/scatter"):
+                    g = local_scatter(idx, val, coef, block_dims[l])
+                with jax.named_scope("inner/update"):
+                    g = g + z_blk + reg.smooth_grad(w_blk)
+                    new_blocks.append(reg.prox(w_blk - eta_m * g, eta_m))
         w_next = jnp.concatenate(new_blocks) if q > 1 else new_blocks[0]
         return w_next, None
 
@@ -503,52 +513,61 @@ def _lazy_inner_epoch(
         else:
             w = carry
         ids, mask, m = inp  # ids: int32[u]; m: int32 inner-step index
-        y = labels[ids]
-        rows = [(block_indices[l][ids], block_values[l][ids]) for l in range(q)]
+        with jax.named_scope("inner/gather"):
+            y = labels[ids]
+            rows = [(block_indices[l][ids], block_values[l][ids])
+                    for l in range(q)]
         w_blocks = split(w)
         if exact:
             for l in range(q):
-                if use_kernels:
-                    w_blocks[l], last_blocks[l] = ops.lazy_block_catchup(
-                        w_blocks[l], last_blocks[l], z_blocks[l], rows[l][0],
-                        eta, m, stop, lam=smooth_lam, lam1=k_l1, lam2=k_l2,
-                    )
-                else:
-                    w_blocks[l], last_blocks[l] = jnp_catchup(
-                        w_blocks[l], last_blocks[l], z_blocks[l], rows[l][0],
-                        m,
-                    )
+                with jax.named_scope("inner/update"):
+                    if use_kernels:
+                        w_blocks[l], last_blocks[l] = ops.lazy_block_catchup(
+                            w_blocks[l], last_blocks[l], z_blocks[l],
+                            rows[l][0], eta, m, stop,
+                            lam=smooth_lam, lam1=k_l1, lam2=k_l2,
+                        )
+                    else:
+                        w_blocks[l], last_blocks[l] = jnp_catchup(
+                            w_blocks[l], last_blocks[l], z_blocks[l],
+                            rows[l][0], m,
+                        )
         # Margins gather only touched ids, which the catch-up just
         # materialized — so coef is bit-identical to the eager epoch's.
-        parts = [
-            _block_margins(rows[l][0], rows[l][1], w_blocks[l], use_kernels)
-            for l in range(q)
-        ]
-        s_m = tree_order_sum(parts)
-        coef = (loss.dvalue(s_m, y) - loss.dvalue(s0[ids], y)) / u
+        with jax.named_scope("inner/gather"):
+            parts = [
+                _block_margins(rows[l][0], rows[l][1], w_blocks[l],
+                               use_kernels)
+                for l in range(q)
+            ]
+            s_m = tree_order_sum(parts)
+            s_anchor = s0[ids]
+        coef = (loss.dvalue(s_m, y) - loss.dvalue(s_anchor, y)) / u
         eta_m = eta * mask
-        for l in range(q):
-            idx, val = rows[l]
-            if exact:
-                if use_kernels:
-                    w_blocks[l] = ops.lazy_block_touch_update(
-                        w_blocks[l], idx, val, coef, z_blocks[l], eta_m,
-                        lam=k_lam, lam1=k_l1, lam2=k_l2,
+        # The lazy touch scatters its ids inside the update.
+        with jax.named_scope("inner/update"):
+            for l in range(q):
+                idx, val = rows[l]
+                if exact:
+                    if use_kernels:
+                        w_blocks[l] = ops.lazy_block_touch_update(
+                            w_blocks[l], idx, val, coef, z_blocks[l], eta_m,
+                            lam=k_lam, lam1=k_l1, lam2=k_l2,
+                        )
+                    else:
+                        w_blocks[l] = jnp_touch(
+                            w_blocks[l], idx, val, coef, z_blocks[l], eta_m
+                        )
+                elif use_kernels:
+                    w_blocks[l] = ops.lazy_block_proba_update(
+                        w_blocks[l], idx, val, coef, z_blocks[l], corr_blocks[l],
+                        eta_m, lam=k_lam, lam1=k_l1, lam2=k_l2,
                     )
                 else:
-                    w_blocks[l] = jnp_touch(
-                        w_blocks[l], idx, val, coef, z_blocks[l], eta_m
+                    w_blocks[l] = jnp_proba(
+                        w_blocks[l], idx, val, coef, z_blocks[l], corr_blocks[l],
+                        eta_m,
                     )
-            elif use_kernels:
-                w_blocks[l] = ops.lazy_block_proba_update(
-                    w_blocks[l], idx, val, coef, z_blocks[l], corr_blocks[l],
-                    eta_m, lam=k_lam, lam1=k_l1, lam2=k_l2,
-                )
-            else:
-                w_blocks[l] = jnp_proba(
-                    w_blocks[l], idx, val, coef, z_blocks[l], corr_blocks[l],
-                    eta_m,
-                )
         w_next = jnp.concatenate(w_blocks) if q > 1 else w_blocks[0]
         if exact:
             last_next = (
@@ -572,14 +591,15 @@ def _lazy_inner_epoch(
     w_blocks = split(w_final)
     last_blocks = split(last_final)
     total = jnp.asarray(m_total, dtype=jnp.int32)
-    for l in range(q):
-        if use_kernels:
-            w_blocks[l] = ops.lazy_block_flush(
-                w_blocks[l], last_blocks[l], z_blocks[l], eta, total, stop,
-                lam=smooth_lam, lam1=k_l1, lam2=k_l2,
-            )
-        else:
-            w_blocks[l] = jnp_flush(w_blocks[l], last_blocks[l], z_blocks[l])
+    with jax.named_scope("inner/update"):
+        for l in range(q):
+            if use_kernels:
+                w_blocks[l] = ops.lazy_block_flush(
+                    w_blocks[l], last_blocks[l], z_blocks[l], eta, total, stop,
+                    lam=smooth_lam, lam1=k_l1, lam2=k_l2,
+                )
+            else:
+                w_blocks[l] = jnp_flush(w_blocks[l], last_blocks[l], z_blocks[l])
     return jnp.concatenate(w_blocks) if q > 1 else w_blocks[0]
 
 

@@ -24,7 +24,9 @@ the backend's ``tree_mode``:
 ``use_kernels=True`` routes the chip-local margin and scatter+update
 through the fused Pallas kernels (:mod:`repro.kernels`), interpret-mode
 off-TPU; ``False`` is the jnp numerics oracle — bit-identical in
-interpret mode.
+interpret mode.  Nothing runs Pallas inside shard_map on a chip: one CPU
+test checks it in interpret mode on a one-device mesh, and ``solve()``
+does not offer it.
 
 Two granularities of compiled step:
 
@@ -35,8 +37,7 @@ Two granularities of compiled step:
   schema — objective, same-iterate optimality residual, metered scalars,
   modeled time — as every other driver, in the data's dtype.
 * :func:`make_outer_iteration` — both phases fused into one jittable
-  call (the AOT/perf shape; ``launch/dryrun`` and ``launch/perf``
-  compile this one).
+  call (the AOT shape ``launch/dryrun`` compiles).
 
 On-device traffic cannot be observed from traced code, so
 :func:`run_fdsvrg_sharded` meters host-side through the backend with the
@@ -54,6 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import losses as losses_lib
 from repro.core.driver import (
     draw_samples,
@@ -129,10 +131,13 @@ def _margin_of(cfg: FDSVRGShardedConfig, w_b, idx, val):
 def _fullgrad_blk(cfg, backend, loss, block, w_blk, bidx, bval, labels):
     """Full-gradient phase on one worker (Alg 1 lines 3-5): one N-vector
     all-reduce, then a purely block-local scatter."""
-    partial = _margin_of(cfg, w_blk, bidx, bval)
-    s0 = backend.device_all_reduce(partial)
-    coeffs = loss.dvalue(s0, labels) / labels.shape[0]
-    z_blk = local_scatter(bidx, bval, coeffs, block)
+    with jax.named_scope("full_grad/margins"):
+        partial = _margin_of(cfg, w_blk, bidx, bval)
+    with jax.named_scope("full_grad/reduce"):
+        s0 = backend.device_all_reduce(partial)
+    with jax.named_scope("full_grad/scatter"):
+        coeffs = loss.dvalue(s0, labels) / labels.shape[0]
+        z_blk = local_scatter(bidx, bval, coeffs, block)
     return z_blk, s0
 
 
@@ -143,20 +148,27 @@ def _inner_scan_blk(cfg, backend, loss, reg, block,
     for every regularizer."""
 
     def step(w_b, ids):
-        idx = bidx[ids]
-        val = bval[ids]
-        y = labels[ids]
-        partial = _margin_of(cfg, w_b, idx, val)
-        s_m = backend.device_all_reduce(partial)
+        with jax.named_scope("inner/gather"):
+            idx = bidx[ids]
+            val = bval[ids]
+            y = labels[ids]
+            partial = _margin_of(cfg, w_b, idx, val)
+        # The per-step all-reduce of u partial margins.
+        with jax.named_scope("inner/reduce"):
+            s_m = backend.device_all_reduce(partial)
         coef = (loss.dvalue(s_m, y) - loss.dvalue(s0[ids], y)) / cfg.batch_size
         if cfg.use_kernels:
-            w_next = ops.fused_block_prox_update(
-                w_b, idx, val, coef, z_blk, cfg.eta,
-                lam=reg.smooth_lam, lam1=reg.prox_l1, lam2=reg.prox_l2,
-            )
+            with jax.named_scope("inner/update"):
+                w_next = ops.fused_block_prox_update(
+                    w_b, idx, val, coef, z_blk, cfg.eta,
+                    lam=reg.smooth_lam, lam1=reg.prox_l1, lam2=reg.prox_l2,
+                )
         else:
-            g = local_scatter(idx, val, coef, block) + z_blk + reg.smooth_grad(w_b)
-            w_next = reg.prox(w_b - cfg.eta * g, cfg.eta)
+            with jax.named_scope("inner/scatter"):
+                g = local_scatter(idx, val, coef, block)
+            with jax.named_scope("inner/update"):
+                g = g + z_blk + reg.smooth_grad(w_b)
+                w_next = reg.prox(w_b - cfg.eta * g, cfg.eta)
         return w_next, None
 
     w_blk, _ = jax.lax.scan(step, w_blk, samples)
@@ -229,7 +241,7 @@ def make_outer_iteration(
     feature_axes: Sequence[str] = ("data", "model"),
     backend: ShardMapBackend | None = None,
 ):
-    """Build the fused one-outer-iteration function (the AOT/perf shape).
+    """Build the fused one-outer-iteration function (the AOT shape).
 
     Signature of the returned fn:
       (w, block_indices, block_values, labels, samples)
@@ -346,9 +358,10 @@ def run_fdsvrg_sharded(
     def epoch(t, rng, w, z_data, s0):
         backend.meter_tree(payload=n)
         backend.charge_cost(COSTS.fd_fullgrad(n=n, nnz=nnz, q=q))
-        samples = draw_samples(rng, n, cfg.inner_steps, u)
-        w = inner_epoch(w, z_data, s0, bidx, bval, data.labels,
-                        jnp.asarray(samples))
+        with obs.span("outer.samples"):
+            samples = jnp.asarray(draw_samples(rng, n, cfg.inner_steps, u))
+        with obs.span("outer.epoch"):
+            w = inner_epoch(w, z_data, s0, bidx, bval, data.labels, samples)
         backend.meter_tree(payload=u, steps=cfg.inner_steps)
         backend.charge_cost(
             COSTS.fd_inner_step(nnz=nnz, q=q, u=u), steps=cfg.inner_steps

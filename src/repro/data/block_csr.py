@@ -67,6 +67,15 @@ class BlockCSR:
     # streaming path; use global_nnz_max() which falls back to the sum of
     # per-block budgets (exact when budgets are tight and rows dense).
     nnz_max: int | None = None
+    # Stored entries (nonzero values) over all blocks, counted on the
+    # host when the layout is built; direct constructions that leave it
+    # None count it once here.
+    stored: int | None = None
+
+    def __post_init__(self) -> None:
+        if self.stored is None:
+            object.__setattr__(self, "stored", sum(
+                int(np.count_nonzero(np.asarray(v))) for v in self.values))
 
     @property
     def num_blocks(self) -> int:
@@ -155,22 +164,18 @@ class BlockCSR:
                 f"partition covers dim={partition.dim}, data has dim={data.dim}"
             )
         if partition.num_blocks == 1:
+            nnz_col = _count_cols(
+                np.asarray(data.indices), np.asarray(data.values), data.dim
+            )
             return cls(
                 partition=partition,
                 indices=(data.indices,),
                 values=(data.values,),
                 labels=data.labels,
                 dim=data.dim,
-                nnz_col=(
-                    jnp.asarray(
-                        _count_cols(
-                            np.asarray(data.indices),
-                            np.asarray(data.values),
-                            data.dim,
-                        )
-                    ),
-                ),
+                nnz_col=(jnp.asarray(nnz_col),),
                 nnz_max=data.nnz_max,
+                stored=int(nnz_col.sum()),
             )
         idx = np.asarray(data.indices)
         val = np.asarray(data.values)
@@ -178,6 +183,7 @@ class BlockCSR:
         block_indices: list[jax.Array] = []
         block_values: list[jax.Array] = []
         block_nnz_col: list[jax.Array] = []
+        stored = 0
         for l in range(partition.num_blocks):
             lo, hi = partition.block(l)
             in_blk = (idx >= lo) & (idx < hi) & (val != 0.0)
@@ -193,9 +199,9 @@ class BlockCSR:
             out_val[rows, pos] = val[rows, cols]
             block_indices.append(jnp.asarray(out_idx))
             block_values.append(jnp.asarray(out_val))
-            block_nnz_col.append(
-                jnp.asarray(_count_cols(out_idx, out_val, hi - lo))
-            )
+            nnz_col = _count_cols(out_idx, out_val, hi - lo)
+            block_nnz_col.append(jnp.asarray(nnz_col))
+            stored += int(nnz_col.sum())
         return cls(
             partition=partition,
             indices=tuple(block_indices),
@@ -204,6 +210,7 @@ class BlockCSR:
             dim=data.dim,
             nnz_col=tuple(block_nnz_col),
             nnz_max=data.nnz_max,
+            stored=stored,
         )
 
     def stacked(self, budget: int | None = None) -> tuple[jax.Array, jax.Array]:
@@ -234,7 +241,7 @@ class BlockCSR:
         return idx, val
 
     def nnz_total(self) -> int:
-        return int(sum(jnp.sum(v != 0.0) for v in self.values))
+        return self.stored
 
 
 def _count_cols(indices: np.ndarray, values: np.ndarray, dim: int) -> np.ndarray:

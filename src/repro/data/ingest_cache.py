@@ -166,6 +166,7 @@ def load_block_csr(
         return None  # key collision or stale format: rebuild, don't trust
     q = partition.num_blocks
     block_indices, block_values, block_nnz_col = [], [], []
+    stored = 0
     for l in range(q):
         slab_path = os.path.join(entry, f"slab_{l:04d}.npz")
         if not os.path.isfile(slab_path):
@@ -182,6 +183,7 @@ def load_block_csr(
             block_indices.append(jnp.asarray(indices))
             block_values.append(jnp.asarray(values))
             block_nnz_col.append(jnp.asarray(slab["nnz_col"]))
+            stored += int(slab["nnz_col"].sum())
     labels = np.load(os.path.join(entry, "labels.npy"))
     return BlockCSR(
         partition=partition,
@@ -191,6 +193,7 @@ def load_block_csr(
         dim=partition.dim,
         nnz_col=tuple(block_nnz_col),
         nnz_max=int(manifest["nnz_max"]),
+        stored=stored,
     )
 
 

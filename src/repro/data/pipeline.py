@@ -517,11 +517,13 @@ def _assemble(partition, acc, labels_parts, stats, lane_multiple, source):
 
     q = partition.num_blocks
     block_indices, block_values, block_nnz_col = [], [], []
+    stored = 0
     for l in range(q):
         idx, val, nnz_col = acc[l].finalize(lane_multiple)
         block_indices.append(jnp.asarray(idx))
         block_values.append(jnp.asarray(val))
         block_nnz_col.append(jnp.asarray(nnz_col))
+        stored += int(nnz_col.sum())
     labels = (
         np.concatenate(labels_parts)
         if labels_parts
@@ -540,6 +542,7 @@ def _assemble(partition, acc, labels_parts, stats, lane_multiple, source):
         dim=stats.dim,
         nnz_col=tuple(block_nnz_col),
         nnz_max=stats.nnz_max,
+        stored=stored,
     )
 
 

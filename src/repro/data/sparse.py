@@ -70,7 +70,8 @@ def margins_rows(
     """s_i = w^T x_i from padded rows; the one definition of the margin
     gather every global-layout path shares (objective, full gradient,
     serial inner loop)."""
-    return jnp.sum(w[indices] * values, axis=-1)
+    with jax.named_scope("margins_rows"):
+        return jnp.sum(w[indices] * values, axis=-1)
 
 
 def margins(data: PaddedCSR, w: jax.Array) -> jax.Array:

@@ -49,6 +49,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import losses as losses_lib
 from repro.core.driver import (
     CheckpointPolicy,
@@ -154,6 +155,30 @@ def make_context(
         backend=backend,
         num_outputs=num_outputs,
     )
+
+
+def _full_grad_lanes(bd: BlockCSR) -> tuple[int, int]:
+    """``(lanes, stored)`` one full gradient over ``bd`` processes: N rows
+    times each block's padded width, summed over blocks, and the stored
+    entries among them (read from the layout, no device work)."""
+    return bd.num_instances * sum(bd.nnz_budgets), bd.stored
+
+
+def _full_grad_snapshot(bd: BlockCSR, loss_name: str, use_kernels: bool) -> Callable:
+    """The ``snapshot`` hook over :func:`_full_grad_blocks`; under the
+    profiler each dispatch adds its lanes and stored entries to the
+    ``full_grad.lanes`` / ``full_grad.stored`` counters (:mod:`repro.obs`)."""
+    lanes, stored = _full_grad_lanes(bd)
+
+    def snapshot(w):
+        obs.count("full_grad.lanes", lanes)
+        obs.count("full_grad.stored", stored)
+        return _full_grad_blocks(
+            bd.indices, bd.values, bd.labels, w,
+            loss_name, bd.block_dims, use_kernels,
+        )
+
+    return snapshot
 
 
 # ---------------------------------------------------------------------------
@@ -288,17 +313,9 @@ class SVRGRule(UpdateRule):
         )
 
     def build_snapshot(self, ctx: RuleContext) -> Callable:
-        bd, loss_name = ctx.block_data, ctx.loss.name
-        use_kernels = self.use_kernels
-
-        def snapshot(w):
-            return _full_grad_blocks(
-                bd.indices, bd.values, bd.labels, w,
-                loss_name, bd.block_dims, use_kernels,
-            )
-
-        if ctx.num_outputs == 1:
-            return snapshot
+        bd, loss_name, k = ctx.block_data, ctx.loss.name, ctx.num_outputs
+        if k == 1:
+            return _full_grad_snapshot(bd, loss_name, self.use_kernels)
 
         def one(labels_j, w_j):
             return _full_grad_blocks(
@@ -307,8 +324,11 @@ class SVRGRule(UpdateRule):
             )
 
         multi = jax.vmap(one, in_axes=(1, 1), out_axes=(1, 1))
+        lanes, stored = _full_grad_lanes(bd)
 
         def snapshot_multi(w):
+            obs.count("full_grad.lanes", k * lanes)
+            obs.count("full_grad.stored", k * stored)
             return multi(bd.labels, w)
 
         return snapshot_multi
@@ -335,30 +355,27 @@ class SVRGRule(UpdateRule):
             # (eta_scale < 1) reuses the compiled scan; eta * 1.0 is
             # bit-exact on the default path.
             eta = cfg.eta * eta_scale
-            samples = draw_samples(rng, n, cfg.inner_steps, u)
-            mask = option_mask(rng, cfg.inner_steps, cfg.option)
-            if multi_epoch is not None:
-                w = multi_epoch(
-                    labels, w, z_data, s0,
-                    jnp.asarray(samples), eta, jnp.asarray(mask),
-                )
-            elif lazy_updates is not None:
-                w = _lazy_inner_epoch(
-                    bd.indices, bd.values, labels,
-                    w, z_data, s0,
-                    jnp.asarray(samples), eta, jnp.asarray(mask),
-                    corrections, loss.name, reg.name, reg.lam, block_dims,
-                    use_kernels, lazy_updates, lam2=reg.lam2,
-                    kernel_lams=kernel_lams,
-                )
-            else:
-                w = _inner_epoch(
-                    bd.indices, bd.values, labels,
-                    w, z_data, s0,
-                    jnp.asarray(samples), eta, jnp.asarray(mask),
-                    loss.name, reg.name, reg.lam, block_dims, use_kernels,
-                    lam2=reg.lam2, kernel_lams=kernel_lams,
-                )
+            with obs.span("outer.samples"):
+                samples = jnp.asarray(draw_samples(rng, n, cfg.inner_steps, u))
+                mask = jnp.asarray(option_mask(rng, cfg.inner_steps, cfg.option))
+            with obs.span("outer.epoch"):
+                if multi_epoch is not None:
+                    w = multi_epoch(labels, w, z_data, s0, samples, eta, mask)
+                elif lazy_updates is not None:
+                    w = _lazy_inner_epoch(
+                        bd.indices, bd.values, labels,
+                        w, z_data, s0, samples, eta, mask,
+                        corrections, loss.name, reg.name, reg.lam, block_dims,
+                        use_kernels, lazy_updates, lam2=reg.lam2,
+                        kernel_lams=kernel_lams,
+                    )
+                else:
+                    w = _inner_epoch(
+                        bd.indices, bd.values, labels,
+                        w, z_data, s0, samples, eta, mask,
+                        loss.name, reg.name, reg.lam, block_dims, use_kernels,
+                        lam2=reg.lam2, kernel_lams=kernel_lams,
+                    )
             # --- inner-loop communication (Alg 1 lines 9-11): one tree
             # round per mini-batch of u·k margins; M steps, in aggregate.
             if backend is not None:
@@ -521,15 +538,7 @@ class SAGARule(UpdateRule):
     supports_option_ii = False
 
     def build_snapshot(self, ctx: RuleContext) -> Callable:
-        bd, loss_name = ctx.block_data, ctx.loss.name
-
-        def snapshot(w):
-            return _full_grad_blocks(
-                bd.indices, bd.values, bd.labels, w,
-                loss_name, bd.block_dims, False,
-            )
-
-        return snapshot
+        return _full_grad_snapshot(ctx.block_data, ctx.loss.name, False)
 
     def build_epoch(self, ctx: RuleContext) -> Callable:
         bd, cfg, backend, loss, reg = (
@@ -549,13 +558,14 @@ class SAGARule(UpdateRule):
                     backend.meter_tree(payload=n)
                     backend.charge_cost(COSTS.fd_saga_init(n=n, nnz=nnz, q=q))
             eta = cfg.eta * eta_scale
-            samples = draw_samples(rng, n, cfg.inner_steps, u)
-            w, z, alpha = _saga_inner_epoch(
-                bd.indices, bd.values, labels,
-                w, state["z"], state["alpha"],
-                jnp.asarray(samples), eta,
-                loss.name, reg.name, reg.lam, block_dims, lam2=reg.lam2,
-            )
+            with obs.span("outer.samples"):
+                samples = jnp.asarray(draw_samples(rng, n, cfg.inner_steps, u))
+            with obs.span("outer.epoch"):
+                w, z, alpha = _saga_inner_epoch(
+                    bd.indices, bd.values, labels,
+                    w, state["z"], state["alpha"], samples, eta,
+                    loss.name, reg.name, reg.lam, block_dims, lam2=reg.lam2,
+                )
             state["z"], state["alpha"] = z, alpha
             if backend is not None:
                 backend.meter_tree(payload=u, steps=cfg.inner_steps)
@@ -626,15 +636,7 @@ class BCDRule(UpdateRule):
     supports_option_ii = False
 
     def build_snapshot(self, ctx: RuleContext) -> Callable:
-        bd, loss_name = ctx.block_data, ctx.loss.name
-
-        def snapshot(w):
-            return _full_grad_blocks(
-                bd.indices, bd.values, bd.labels, w,
-                loss_name, bd.block_dims, False,
-            )
-
-        return snapshot
+        return _full_grad_snapshot(ctx.block_data, ctx.loss.name, False)
 
     def build_epoch(self, ctx: RuleContext) -> Callable:
         bd, cfg, backend, loss, reg = (
@@ -648,14 +650,15 @@ class BCDRule(UpdateRule):
         def epoch(t, rng, w, z_data, s0, eta_scale=1.0):
             eta = cfg.eta * eta_scale
             s = s0
-            for m in range(cfg.inner_steps):
-                l = (state["cursor"] + m) % q
-                idx, val = bd.block(l)
-                w, s = _bcd_block_step(
-                    idx, val, labels, w, s, eta,
-                    loss.name, reg.name, reg.lam,
-                    bounds[l], block_dims[l], lam2=reg.lam2,
-                )
+            with obs.span("outer.epoch"):
+                for m in range(cfg.inner_steps):
+                    l = (state["cursor"] + m) % q
+                    idx, val = bd.block(l)
+                    w, s = _bcd_block_step(
+                        idx, val, labels, w, s, eta,
+                        loss.name, reg.name, reg.lam,
+                        bounds[l], block_dims[l], lam2=reg.lam2,
+                    )
             state["cursor"] = (state["cursor"] + cfg.inner_steps) % q
             if backend is not None:
                 backend.meter_tree(payload=n, steps=cfg.inner_steps)

@@ -37,6 +37,8 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
+
 
 def bucket_width(nnz: int, *, min_width: int = 8) -> int:
     """The padded nnz width a request with ``nnz`` entries buckets to:
@@ -84,6 +86,7 @@ class Batch:
     t_flush: float
     cause: str  # "full" | "deadline" | "drain"
     snapshot: object | None = None
+    seq: int = 0  # flush sequence number: the batch's id in repro.obs spans
 
     @property
     def n_valid(self) -> int:
@@ -120,6 +123,7 @@ class MicroBatcher:
         self.clock = clock
         self._buckets: dict[int, list[Request]] = {}
         self._next_id = 0
+        self._flushed = 0
         # flushed-shape histogram {(rows, width): count} and flush causes
         self.bucket_counts: dict[tuple[int, int], int] = {}
         self.flush_causes: dict[str, int] = {}
@@ -182,12 +186,16 @@ class MicroBatcher:
     def _flush(self, width: int, reqs: list[Request], cause: str,
                now: float) -> Batch:
         rows = min(_pow2_rows(len(reqs)), self.max_batch)
-        dtype = reqs[0].values.dtype
-        indices = np.zeros((rows, width), dtype=np.int32)
-        values = np.zeros((rows, width), dtype=dtype)
-        for r, req in enumerate(reqs):
-            indices[r, : req.nnz] = req.indices
-            values[r, : req.nnz] = req.values
+        seq = self._flushed
+        self._flushed += 1
+        with obs.span("serve.pack", batch=seq, rows=rows, width=width,
+                      valid=len(reqs)):
+            dtype = reqs[0].values.dtype
+            indices = np.zeros((rows, width), dtype=np.int32)
+            values = np.zeros((rows, width), dtype=dtype)
+            for r, req in enumerate(reqs):
+                indices[r, : req.nnz] = req.indices
+                values[r, : req.nnz] = req.values
         shape = (rows, width)
         self.bucket_counts[shape] = self.bucket_counts.get(shape, 0) + 1
         self.flush_causes[cause] = self.flush_causes.get(cause, 0) + 1
@@ -197,4 +205,5 @@ class MicroBatcher:
             values=values,
             t_flush=now,
             cause=cause,
+            seq=seq,
         )

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.data.sparse import margins_rows
 from repro.kernels import ops
 
@@ -60,24 +61,36 @@ def batched_margins(indices, values, w, *, use_kernels: bool = False) -> np.ndar
     ``use_kernels=True`` runs the Pallas gather kernel (interpret-mode
     off-TPU); both paths are bit-identical to each other.
     """
-    idx = jnp.asarray(indices, dtype=jnp.int32)
-    val = jnp.asarray(values)
+    with obs.span("serve.engine"):
+        return _margins(indices, values, w, use_kernels)
+
+
+def _margins(indices, values, w, use_kernels: bool) -> np.ndarray:
+    """:func:`batched_margins` inside its ``serve.engine`` span: the
+    uploads, the jitted call(s) and the download each under a span of
+    their own."""
+    with obs.span("serve.h2d"):
+        idx = jnp.asarray(indices, dtype=jnp.int32)
+        val = jnp.asarray(values)
+        w = jnp.asarray(w)
     if idx.ndim != 2 or idx.shape != val.shape:
         raise ValueError(
             f"need matching [n, width] arrays, got {idx.shape} / {val.shape}"
         )
-    w = jnp.asarray(w)
     if w.ndim not in (1, 2):
         raise ValueError(f"w must be [d] or [d, k], got shape {w.shape}")
     if idx.shape[0] == 0:
         shape = (0,) if w.ndim == 1 else (0, int(w.shape[1]))
         return np.zeros(shape, dtype=np.asarray(val).dtype)
     column = ops.sparse_margins if use_kernels else _ref_margins
-    if w.ndim == 1:
-        return np.asarray(column(idx, val, w))
-    return np.column_stack(
-        [np.asarray(column(idx, val, w[:, j])) for j in range(w.shape[1])]
-    )
+    with obs.span("serve.dispatch"):
+        if w.ndim == 1:
+            out = [column(idx, val, w)]
+        else:
+            out = [column(idx, val, w[:, j]) for j in range(w.shape[1])]
+    with obs.span("serve.d2h"):
+        out = [np.asarray(o) for o in out]
+    return out[0] if w.ndim == 1 else np.column_stack(out)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,22 +214,25 @@ class PredictionEngine:
             return prev
 
     def margins(self, indices, values, *,
-                snapshot: WeightSnapshot | None = None) -> np.ndarray:
+                snapshot: WeightSnapshot | None = None,
+                batch: int | None = None) -> np.ndarray:
         """Margins for one padded batch: ``[n]`` for binary snapshots,
         ``[n, k]`` for multi-output.  ``snapshot`` overrides the current
         one (the serve loop passes the version a batch was pinned to at
-        flush time — see :mod:`repro.serve.loop`)."""
-        snap = self.snapshot if snapshot is None else snapshot
-        values = np.asarray(values)
-        n, width = values.shape if values.ndim == 2 else (0, 0)
-        if n:
-            self.compiled_shapes.add(
-                (n, width, snap.num_outputs, str(values.dtype),
-                 self.use_kernels)
-            )
-        out = batched_margins(
-            indices, values, snap.w, use_kernels=self.use_kernels
-        )
-        self.batches_served += 1
-        self.rows_served += n
+        flush time — see :mod:`repro.serve.loop`); ``batch`` is the
+        batch's flush sequence number (``Batch.seq``), the id of its
+        ``serve.engine`` span."""
+        ids = {} if batch is None else {"batch": batch}
+        with obs.span("serve.engine", **ids):
+            snap = self.snapshot if snapshot is None else snapshot
+            values = np.asarray(values)
+            n, width = values.shape if values.ndim == 2 else (0, 0)
+            if n:
+                self.compiled_shapes.add(
+                    (n, width, snap.num_outputs, str(values.dtype),
+                     self.use_kernels)
+                )
+            out = _margins(indices, values, snap.w, self.use_kernels)
+            self.batches_served += 1
+            self.rows_served += n
         return out

@@ -164,7 +164,8 @@ def run_serve_loop(
         for batch in batches:
             snap = batch.snapshot
             t0 = clock()
-            out = engine.margins(batch.indices, batch.values, snapshot=snap)
+            out = engine.margins(batch.indices, batch.values, snapshot=snap,
+                                 batch=batch.seq)
             t1 = clock()
             serve_wall += t1 - t0
             num_batches += 1

@@ -64,6 +64,9 @@ def _assert_blocks_equal(a: BlockCSR, b: BlockCSR) -> None:
     assert a.partition.bounds == b.partition.bounds
     assert a.nnz_budgets == b.nnz_budgets
     assert a.global_nnz_max() == b.global_nnz_max()
+    # Every way of making the layout counts its stored entries on the host.
+    assert a.stored == b.stored == sum(
+        int(np.count_nonzero(np.asarray(v))) for v in a.values)
     np.testing.assert_array_equal(np.asarray(a.labels), np.asarray(b.labels))
     for l in range(a.num_blocks):
         np.testing.assert_array_equal(
