@@ -79,7 +79,7 @@ def bench_inner_epoch(quick: bool) -> tuple[list[list], dict]:
 
     for rname, reg in (("l2", losses.l2(1e-4)), ("l1", losses.l1(1e-4))):
         z, s0 = fdsvrg._full_grad_blocks(
-            bd.indices, bd.values, data.labels, w0, "logistic",
+            bd.groups, data.labels, w0, "logistic",
             bd.block_dims, False,
         )
         corr = fdsvrg._lazy_corrections(

@@ -65,7 +65,9 @@ from repro.core.driver import (
 from repro.core.partition import FeaturePartition, balanced
 from repro.dist import COSTS, ClusterModel, Collectives, SimBackend, tree_order_sum
 from repro.data.sparse import PaddedCSR, margins_rows, scatter_grad
-from repro.data.block_csr import BlockCSR, local_margins, local_scatter
+from repro.data.block_csr import (
+    BlockCSR, RowGroups, local_margins, local_scatter,
+)
 from repro.kernels import ops
 
 
@@ -144,23 +146,47 @@ def _block_margins(idx, val, w_block, use_kernels: bool):
     return local_margins(idx, val, w_block)
 
 
+def _group_margins(groups: RowGroups, w_block, use_kernels: bool):
+    """One block's partial margins over its row groups, in row order."""
+    parts = [
+        _block_margins(idx, val, w_block, use_kernels)
+        for idx, val in zip(groups.indices, groups.values)
+    ]
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate(parts)[groups.order]
+
+
+def _group_scatter(groups: RowGroups, coeffs, block_dim: int):
+    """sum_i coeffs_i * x^(l)_i over one block's row groups, ``coeffs``
+    in row order; one group is exactly :func:`local_scatter`."""
+    if len(groups.indices) == 1:
+        return local_scatter(groups.indices[0], groups.values[0], coeffs,
+                             block_dim)
+    z = jnp.zeros((block_dim,), dtype=groups.values[0].dtype)
+    for idx, val, rows in zip(groups.indices, groups.values, groups.rows):
+        z = z.at[idx.reshape(-1)].add((val * coeffs[rows][:, None]).reshape(-1))
+    return z
+
+
 @functools.partial(
     jax.jit, static_argnames=("loss_name", "block_dims", "use_kernels")
 )
 def _full_grad_blocks(
-    block_indices, block_values, labels, w, loss_name, block_dims, use_kernels
+    block_groups, labels, w, loss_name, block_dims, use_kernels
 ):
     """Feature-decomposed full gradient: per-block partial margins summed
     in tree order (Alg 1 lines 3-4), then a purely block-local scatter
-    (line 5).  Returns the concatenated z and the cached margins s0."""
+    (line 5), both over each block's row groups (``BlockCSR.groups``), so
+    only the lanes of each row's length class are touched.  Returns the
+    concatenated z and the cached margins s0, in row order."""
     loss = losses_lib.LOSSES[loss_name]
     q = len(block_dims)
     bounds = _bounds(block_dims)
     with jax.named_scope("full_grad/margins"):
         parts = [
-            _block_margins(
-                block_indices[l],
-                block_values[l],
+            _group_margins(
+                block_groups[l],
                 jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
                 use_kernels,
             )
@@ -170,8 +196,7 @@ def _full_grad_blocks(
     with jax.named_scope("full_grad/scatter"):
         coeffs = loss.dvalue(s0, labels) / labels.shape[0]
         z_blocks = [
-            local_scatter(block_indices[l], block_values[l], coeffs,
-                          block_dims[l])
+            _group_scatter(block_groups[l], coeffs, block_dims[l])
             for l in range(q)
         ]
     z_data = jnp.concatenate(z_blocks) if q > 1 else z_blocks[0]
@@ -729,9 +754,14 @@ def _sim_margins(idx, val, w_block, use_kernels):
     return _block_margins(idx, val, w_block, use_kernels)
 
 
+@functools.partial(jax.jit, static_argnames=("use_kernels",))
+def _sim_full_margins(groups, w_block, use_kernels):
+    return _group_margins(groups, w_block, use_kernels)
+
+
 @functools.partial(jax.jit, static_argnames=("block_dim",))
-def _sim_scatter(idx, val, coeffs, block_dim):
-    return local_scatter(idx, val, coeffs, block_dim)
+def _sim_scatter(groups, coeffs, block_dim):
+    return _group_scatter(groups, coeffs, block_dim)
 
 
 @functools.partial(
@@ -860,13 +890,13 @@ def fdsvrg_worker_simulation(
         # line 5: purely local scatter of the full-gradient block.
         blocks = split(w)
         partials = [
-            _sim_margins(*block_data.block(l), blocks[l], use_kernels)
+            _sim_full_margins(block_data.groups[l], blocks[l], use_kernels)
             for l in range(q)
         ]
         s0 = tree_order_sum(partials)
         coeffs0 = loss.dvalue(s0, labels) / n
         z_blocks = [
-            _sim_scatter(*block_data.block(l), coeffs0, block_dims[l])
+            _sim_scatter(block_data.groups[l], coeffs0, block_dims[l])
             for l in range(q)
         ]
         z_data = jnp.concatenate(z_blocks) if q > 1 else z_blocks[0]

@@ -26,6 +26,12 @@ matches the global layout.
 :func:`local_margins` / :func:`local_scatter` are the two block-local hot
 paths; they are also the numerics contract for the fused Pallas kernels in
 :mod:`repro.kernels` (``sparse_margin``, ``fused_update``).
+
+Each block also carries its rows grouped by stored length
+(:class:`RowGroups`): the full gradient walks every row, so it reads
+the groups, each padded only to its own multiple of 128 lanes, and not
+the ``[N, nnz_l]`` slab.  The inner epoch samples rows by id and keeps
+reading the slab.
 """
 
 from __future__ import annotations
@@ -41,6 +47,83 @@ from repro.data.sparse import PaddedCSR
 
 if TYPE_CHECKING:  # import would cycle through repro.core.__init__ at runtime
     from repro.core.partition import FeaturePartition
+
+
+#: Row groups pad to a multiple of this many lanes (a TPU vector
+#: register's width).
+GROUP_LANES = 128
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class RowGroups:
+    """One block's rows grouped by length class, for the full gradient.
+
+    A row's length is one past its last stored (nonzero) entry; its
+    class is that length rounded up to a multiple of :data:`GROUP_LANES`,
+    at least one multiple, at most the slab's width.  Rows are ordered
+    stably by class, so row order holds inside a group.  Group b keeps
+    the first ``W_b`` (its class) lanes of its rows, which hold every
+    stored entry of them.  When every row falls in one class whose
+    width is the slab's, the one group *is* the slab, with the identity
+    order.
+    """
+
+    indices: tuple[jax.Array, ...]  # per group: int32[N_b, W_b], local ids
+    values: tuple[jax.Array, ...]  # per group: float[N_b, W_b]
+    rows: tuple[jax.Array, ...]  # per group: int32[N_b], source row ids
+    # int32[N]: source row i sits at position order[i] of the groups
+    # concatenated, so concat(per-group margins)[order] is in row order.
+    order: jax.Array
+
+    @property
+    def lanes(self) -> int:
+        """Lanes one pass over the groups touches: sum of N_b * W_b."""
+        return sum(int(i.size) for i in self.indices)
+
+
+def row_groups(
+    indices: np.ndarray,
+    values: np.ndarray,
+    slab: tuple[jax.Array, jax.Array],
+) -> RowGroups:
+    """Group one block's ``[N, W]`` rows by length class (host-side).
+
+    ``indices`` / ``values`` are the host copy of the slab and ``slab``
+    the layout's own arrays, which become the one group when all rows
+    fall in one class as wide as the slab.
+    """
+    n, width = values.shape
+    stored = values != 0.0
+    length = np.where(
+        stored.any(axis=1), width - np.argmax(stored[:, ::-1], axis=1), 0
+    )
+    lanes = np.maximum(-(-length // GROUP_LANES), 1) * GROUP_LANES
+    cls = np.minimum(lanes, width)
+    widths = np.unique(cls)
+    if widths.size <= 1:
+        w_b = int(widths[0]) if widths.size else width
+        if w_b == width:
+            idx_b, val_b = slab
+        else:
+            idx_b = jnp.asarray(indices[:, :w_b])
+            val_b = jnp.asarray(values[:, :w_b])
+        ident = jnp.arange(n, dtype=jnp.int32)
+        return RowGroups((idx_b,), (val_b,), (ident,), ident)
+    perm = np.argsort(cls, kind="stable").astype(np.int32)
+    order = np.empty(n, np.int32)
+    order[perm] = np.arange(n, dtype=np.int32)
+    starts = np.searchsorted(cls[perm], widths)
+    ends = np.append(starts[1:], n)
+    grouped = [
+        (perm[a:b], int(w_b)) for a, b, w_b in zip(starts, ends, widths)
+    ]
+    return RowGroups(
+        indices=tuple(jnp.asarray(indices[r, :w_b]) for r, w_b in grouped),
+        values=tuple(jnp.asarray(values[r, :w_b]) for r, w_b in grouped),
+        rows=tuple(jnp.asarray(r) for r, _ in grouped),
+        order=jnp.asarray(order),
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,11 +154,23 @@ class BlockCSR:
     # host when the layout is built; direct constructions that leave it
     # None count it once here.
     stored: int | None = None
+    # Per block: its rows grouped by length class (row_groups), which
+    # the full gradient reads.  Built with the layout; direct
+    # constructions that leave it None build it once here.
+    groups: tuple[RowGroups, ...] | None = None
 
     def __post_init__(self) -> None:
+        if self.stored is not None and self.groups is not None:
+            return
+        host = [(np.asarray(i), np.asarray(v))
+                for i, v in zip(self.indices, self.values)]
         if self.stored is None:
             object.__setattr__(self, "stored", sum(
-                int(np.count_nonzero(np.asarray(v))) for v in self.values))
+                int(np.count_nonzero(v)) for _, v in host))
+        if self.groups is None:
+            object.__setattr__(self, "groups", tuple(
+                row_groups(i, v, slab)
+                for (i, v), slab in zip(host, zip(self.indices, self.values))))
 
     @property
     def num_blocks(self) -> int:
@@ -164,9 +259,8 @@ class BlockCSR:
                 f"partition covers dim={partition.dim}, data has dim={data.dim}"
             )
         if partition.num_blocks == 1:
-            nnz_col = _count_cols(
-                np.asarray(data.indices), np.asarray(data.values), data.dim
-            )
+            idx, val = np.asarray(data.indices), np.asarray(data.values)
+            nnz_col = _count_cols(idx, val, data.dim)
             return cls(
                 partition=partition,
                 indices=(data.indices,),
@@ -176,6 +270,7 @@ class BlockCSR:
                 nnz_col=(jnp.asarray(nnz_col),),
                 nnz_max=data.nnz_max,
                 stored=int(nnz_col.sum()),
+                groups=(row_groups(idx, val, (data.indices, data.values)),),
             )
         idx = np.asarray(data.indices)
         val = np.asarray(data.values)
@@ -183,6 +278,7 @@ class BlockCSR:
         block_indices: list[jax.Array] = []
         block_values: list[jax.Array] = []
         block_nnz_col: list[jax.Array] = []
+        block_groups: list[RowGroups] = []
         stored = 0
         for l in range(partition.num_blocks):
             lo, hi = partition.block(l)
@@ -199,6 +295,8 @@ class BlockCSR:
             out_val[rows, pos] = val[rows, cols]
             block_indices.append(jnp.asarray(out_idx))
             block_values.append(jnp.asarray(out_val))
+            block_groups.append(row_groups(
+                out_idx, out_val, (block_indices[-1], block_values[-1])))
             nnz_col = _count_cols(out_idx, out_val, hi - lo)
             block_nnz_col.append(jnp.asarray(nnz_col))
             stored += int(nnz_col.sum())
@@ -211,6 +309,7 @@ class BlockCSR:
             nnz_col=tuple(block_nnz_col),
             nnz_max=data.nnz_max,
             stored=stored,
+            groups=tuple(block_groups),
         )
 
     def stacked(self, budget: int | None = None) -> tuple[jax.Array, jax.Array]:

@@ -158,10 +158,11 @@ def make_context(
 
 
 def _full_grad_lanes(bd: BlockCSR) -> tuple[int, int]:
-    """``(lanes, stored)`` one full gradient over ``bd`` processes: N rows
-    times each block's padded width, summed over blocks, and the stored
-    entries among them (read from the layout, no device work)."""
-    return bd.num_instances * sum(bd.nnz_budgets), bd.stored
+    """``(lanes, stored)`` one full gradient over ``bd`` processes: each
+    block's row groups, N_b rows times width W_b, summed over groups and
+    blocks, and the stored entries among them (read from the layout, no
+    device work)."""
+    return sum(g.lanes for g in bd.groups), bd.stored
 
 
 def _full_grad_snapshot(bd: BlockCSR, loss_name: str, use_kernels: bool) -> Callable:
@@ -174,8 +175,7 @@ def _full_grad_snapshot(bd: BlockCSR, loss_name: str, use_kernels: bool) -> Call
         obs.count("full_grad.lanes", lanes)
         obs.count("full_grad.stored", stored)
         return _full_grad_blocks(
-            bd.indices, bd.values, bd.labels, w,
-            loss_name, bd.block_dims, use_kernels,
+            bd.groups, bd.labels, w, loss_name, bd.block_dims, use_kernels,
         )
 
     return snapshot
@@ -319,8 +319,7 @@ class SVRGRule(UpdateRule):
 
         def one(labels_j, w_j):
             return _full_grad_blocks(
-                bd.indices, bd.values, labels_j, w_j,
-                loss_name, bd.block_dims, False,
+                bd.groups, labels_j, w_j, loss_name, bd.block_dims, False,
             )
 
         multi = jax.vmap(one, in_axes=(1, 1), out_axes=(1, 1))

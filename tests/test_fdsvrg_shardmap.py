@@ -68,7 +68,7 @@ def test_shardmap_single_device_matches_serial():
     w_ref = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
         z, s0 = _full_grad_blocks(
-            block.indices, block.values, data.labels, w_ref,
+            block.groups, data.labels, w_ref,
             "logistic", block.block_dims, False,
         )
         samples = rng.integers(0, data.num_instances, size=(inner, u)).astype(np.int32)
@@ -297,7 +297,7 @@ _SUBPROCESS_PROG = textwrap.dedent(
     block = BlockCSR.from_padded(data, balanced(data.dim, 1))
     w_ref = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
-        z, s0 = _full_grad_blocks(block.indices, block.values, data.labels, w_ref,
+        z, s0 = _full_grad_blocks(block.groups, data.labels, w_ref,
                                   "logistic", block.block_dims, False)
         w_ref = _inner_epoch(block.indices, block.values, data.labels, w_ref, z, s0,
                              jnp.asarray(all_samples[t]), eta,

@@ -143,7 +143,7 @@ def test_exact_epoch_matches_per_step_oracle(q, reg_name, use_kernels, option):
         else (np.arange(m_steps) < m_steps - 4).astype(np.float32)
     )
     z, s0 = fdsvrg._full_grad_blocks(
-        bd.indices, bd.values, data.labels, w0, "logistic", bd.block_dims,
+        bd.groups, data.labels, w0, "logistic", bd.block_dims,
         use_kernels,
     )
     want = oracle_epoch(
@@ -174,7 +174,7 @@ def test_never_touched_features_match_oracle():
     mask = np.ones(m_steps, np.float32)
     for reg in REGS.values():
         z, s0 = fdsvrg._full_grad_blocks(
-            bd.indices, bd.values, data.labels, w0, "logistic",
+            bd.groups, data.labels, w0, "logistic",
             bd.block_dims, False,
         )
         want = oracle_epoch(
@@ -212,7 +212,7 @@ def test_first_and_last_step_only_touches():
     mask = np.ones(m_steps, np.float32)
     for reg in REGS.values():
         z, s0 = fdsvrg._full_grad_blocks(
-            bd.indices, bd.values, data.labels, w0, "logistic",
+            bd.groups, data.labels, w0, "logistic",
             bd.block_dims, False,
         )
         want = oracle_epoch(
@@ -251,7 +251,7 @@ def test_padding_collision_id_zero_value_zero():
     for reg in REGS.values():
         for use_kernels in (False, True):
             z, s0 = fdsvrg._full_grad_blocks(
-                bd.indices, bd.values, data.labels, w0, "logistic",
+                bd.groups, data.labels, w0, "logistic",
                 bd.block_dims, use_kernels,
             )
             want = oracle_epoch(
@@ -478,7 +478,7 @@ def test_proba_expected_update_matches_dense():
     w0 = jnp.asarray(rng.normal(size=d).astype(np.float32) * 0.1)
     eta = 0.05
     z, s0 = fdsvrg._full_grad_blocks(
-        bd.indices, bd.values, data.labels, w0, "logistic", bd.block_dims,
+        bd.groups, data.labels, w0, "logistic", bd.block_dims,
         False,
     )
     corr = fdsvrg._lazy_corrections(bd, n, u, "proba")

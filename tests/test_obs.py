@@ -144,7 +144,9 @@ def test_solve_records_its_span_tree_and_counters(tmp_path):
 
     bd = BLOCK_CACHE.get(data, 2)
     counters = obs.totals()["counters"]
-    assert counters["full_grad.lanes"] == 3 * 64 * sum(bd.nnz_budgets)
+    # Lanes of the row groups the full gradient walks: sum of N_b * W_b.
+    assert counters["full_grad.lanes"] == 3 * sum(
+        idx.shape[0] * idx.shape[1] for g in bd.groups for idx in g.indices)
     assert counters["full_grad.stored"] == 3 * int(np.count_nonzero(
         np.asarray(data.values)))
 

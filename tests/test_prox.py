@@ -250,7 +250,7 @@ def test_prox_shardmap_matches_serial_reference(reg_name, lam, lam2, use_kernels
     w_ref = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
         z, s0 = _full_grad_blocks(
-            block.indices, block.values, data.labels, w_ref,
+            block.groups, data.labels, w_ref,
             "logistic", block.block_dims, False,
         )
         w_ref = _inner_epoch(

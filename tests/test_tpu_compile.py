@@ -76,6 +76,8 @@ def compile_for(one_chip):
         (Q8_BLOCK, FD_BATCH, 47),  # narrowest q=8 block
         (Q4_BLOCK, NEWS20_N, Q4_NNZ),  # full-gradient margins, q=4 mesh
         (Q4_BLOCK, FD_BATCH, Q4_NNZ),  # inner-step margins, q=4 mesh
+        (SERVE_D, 3_512, 256),  # full-gradient row groups at q=1:
+        (SERVE_D, 1_157, 1_024),  # news20's narrowest and widest
         (SERVE_D, SERVE_ROWS, SERVE_WIDTH),  # widest serving batch
         (SERVE_D, 1, 8),  # a lone narrow request
     ],
