@@ -209,9 +209,10 @@ def sharded_placement_phase(data, mesh, result):
         eta=TRAIN["eta"], inner_steps=TRAIN["inner_steps"],
         batch_size=TRAIN["batch_size"],
     )
-    bidx, bval = BlockCSR.from_padded(padded, balanced(dim, q)).stacked()
+    placed = BlockCSR.from_padded(padded, balanced(dim, q)).on_mesh(
+        mesh, ("model",))
     w = jnp.pad(result.w, (0, dim - data.dim))
-    z, s0 = make_fullgrad(mesh, cfg, ("model",))(w, bidx, bval, data.labels)
+    z, s0 = make_fullgrad(mesh, cfg, ("model",))(w, placed.groups, placed.labels)
     shards = z.addressable_shards
     starts = sorted(s.index[0].start or 0 for s in shards)
     if len({s.device for s in shards}) != q or starts != [

@@ -29,13 +29,14 @@ from __future__ import annotations
 
 from collections import OrderedDict
 
-from repro.core.partition import balanced
+from repro.core.partition import FeaturePartition, balanced
 from repro.data.block_csr import BlockCSR
 from repro.data.sparse import PaddedCSR
 
 
 class BlockCache:
-    """A bounded ``(data, q) -> BlockCSR`` cache with per-sweep scope."""
+    """A bounded ``(data, partition) -> BlockCSR`` cache with per-sweep
+    scope."""
 
     def __init__(self, max_entries: int = 4) -> None:
         self.max_entries = int(max_entries)
@@ -43,13 +44,21 @@ class BlockCache:
             tuple[int, int], tuple[object, BlockCSR]
         ] = OrderedDict()
 
-    def get(self, data: PaddedCSR, q: int) -> BlockCSR:
-        """The BlockCSR of ``data`` at ``q`` blocks, built at most once."""
-        hit = self._lookup(data, q)
+    def get(
+        self,
+        data: PaddedCSR,
+        q: int,
+        partition: FeaturePartition | None = None,
+    ) -> BlockCSR:
+        """The BlockCSR of ``data`` at ``q`` blocks, built at most once;
+        ``partition`` defaults to ``balanced(data.dim, q)`` (a mesh asks
+        for its padded one, :func:`repro.core.fdsvrg_shardmap.mesh_partition`)."""
+        partition = partition or balanced(data.dim, q)
+        hit = self._lookup(data, partition)
         if hit is not None:
             return hit
-        block = BlockCSR.from_padded(data, balanced(data.dim, q))
-        self._insert(data, q, block)
+        block = BlockCSR.from_padded(data, partition)
+        self._insert(data, partition, block)
         return block
 
     def get_source(
@@ -70,30 +79,30 @@ class BlockCache:
         """
         from repro.data.ingest_cache import get_or_build
 
-        hit = self._lookup(source, q)
+        partition = balanced(source.stats().dim, q)
+        hit = self._lookup(source, partition)
         if hit is not None:
             return hit
-        partition = balanced(source.stats().dim, q)
         outcome = get_or_build(
             source, partition, cache_dir=cache_dir, chunk_rows=chunk_rows
         )
-        self._insert(source, q, outcome.data)
+        self._insert(source, partition, outcome.data)
         return outcome.data
 
-    def _lookup(self, owner, q: int) -> BlockCSR | None:
-        key = (id(owner), q)
+    def _lookup(self, owner, partition: FeaturePartition) -> BlockCSR | None:
+        key = (id(owner), partition.bounds)
         hit = self._entries.get(key)
         if hit is not None and hit[0] is owner:
             self._entries.move_to_end(key)
             return hit[1]
         return None
 
-    def _insert(self, owner, q: int, block: BlockCSR) -> None:
+    def _insert(self, owner, partition: FeaturePartition, block: BlockCSR) -> None:
         # New owner object: the sweep moved on — drop other data sets'
         # entries (and any stale entry whose id() was recycled).
         for k in [k for k, v in self._entries.items() if v[0] is not owner]:
             del self._entries[k]
-        self._entries[(id(owner), q)] = (owner, block)
+        self._entries[(id(owner), partition.bounds)] = (owner, block)
         while len(self._entries) > self.max_entries:
             self._entries.popitem(last=False)
 

@@ -60,9 +60,14 @@ from repro.core.fdsvrg import (
     run_fdsvrg,
     run_serial_svrg,
 )
-from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, run_fdsvrg_sharded
+from repro.core.fdsvrg_shardmap import (
+    FDSVRGShardedConfig,
+    mesh_partition,
+    run_fdsvrg_sharded,
+)
 from repro.core.partition import balanced
 from repro.data import datasets
+from repro.data.block_csr import BlockCSR
 from repro.data.pipeline import as_source, is_source
 from repro.dist import SimBackend, make_mesh
 from repro.optim.update_rules import BCDRule, SAGARule, make_context, run_with_rule
@@ -212,6 +217,11 @@ def _validate(spec: ExperimentSpec, info: MethodInfo) -> None:
         raise ValueError(
             f"method {info.name!r} does not run on a mesh; mesh= is only "
             "meaningful for shard_map methods (fdsvrg_sharded)"
+        )
+    if isinstance(spec.data, BlockCSR) and not info.needs_mesh:
+        raise ValueError(
+            f"method {info.name!r} takes data= as a PaddedCSR; a BlockCSR "
+            "of per-device blocks is the mesh driver's (fdsvrg_sharded)"
         )
     if spec.tree_mode != "psum" and not info.needs_mesh:
         raise ValueError(
@@ -468,10 +478,15 @@ def _solve_fdsvrg_sim(spec, data, p, mesh) -> RunResult:
     summary="Algorithm 1, deployable shard_map over the mesh's feature axes",
 )
 def _solve_fdsvrg_sharded(spec, data, p, mesh) -> RunResult:
+    # A BlockCSR (BlockCSR.from_blocks: blocks made one per device) runs
+    # as it is; a PaddedCSR is re-indexed once per data set into the
+    # mesh's padded partition through the shared cache.
+    if not isinstance(data, BlockCSR):
+        data = BLOCK_CACHE.get(data, p.q, mesh_partition(data.dim, p.q))
     cfg = FDSVRGShardedConfig(
         dim=data.dim,
         num_instances=data.num_instances,
-        nnz_max=data.nnz_max,
+        nnz_max=data.global_nnz_max(),
         eta=p.eta,
         inner_steps=p.inner_steps,
         batch_size=p.batch_size,

@@ -34,6 +34,7 @@ from typing import Any
 import jax
 
 from repro.core import losses as losses_lib
+from repro.data.block_csr import BlockCSR
 from repro.data.sparse import PaddedCSR
 from repro.dist import ClusterModel
 
@@ -46,7 +47,9 @@ class ExperimentSpec:
     """A complete, declarative description of one optimization run.
 
     Exactly one of ``dataset`` (a :mod:`repro.data.datasets` key),
-    ``data`` (an in-memory :class:`~repro.data.sparse.PaddedCSR`), or
+    ``data`` (an in-memory :class:`~repro.data.sparse.PaddedCSR`; for
+    ``fdsvrg_sharded`` also a :class:`~repro.data.block_csr.BlockCSR`
+    whose blocks were made one per device), or
     ``source`` (a :class:`~repro.data.pipeline.DataSource` or a LibSVM
     file path — the streaming out-of-core path) must be set.
     ``eq=False``: specs carry device arrays (``data``, ``init_w``), so
@@ -55,7 +58,7 @@ class ExperimentSpec:
 
     method: str
     dataset: str | None = None
-    data: PaddedCSR | None = None
+    data: PaddedCSR | BlockCSR | None = None
     # Streaming ingestion (repro.data.pipeline): a DataSource instance or
     # a path to a LibSVM file.  Worker slabs are built incrementally —
     # bit-identical to the in-memory path — and never materialize the

@@ -66,7 +66,7 @@ from repro.core.partition import FeaturePartition, balanced
 from repro.dist import COSTS, ClusterModel, Collectives, SimBackend, tree_order_sum
 from repro.data.sparse import PaddedCSR, margins_rows, scatter_grad
 from repro.data.block_csr import (
-    BlockCSR, RowGroups, local_margins, local_scatter,
+    BlockCSR, block_margins, group_margins, group_scatter, local_scatter,
 )
 from repro.kernels import ops
 
@@ -139,36 +139,6 @@ def _bounds(block_dims: tuple[int, ...]) -> tuple[int, ...]:
     return tuple(b)
 
 
-def _block_margins(idx, val, w_block, use_kernels: bool):
-    """Per-block partial margins over block-LOCAL rows (gather, no mask)."""
-    if use_kernels:
-        return ops.sparse_margins(idx, val, w_block)
-    return local_margins(idx, val, w_block)
-
-
-def _group_margins(groups: RowGroups, w_block, use_kernels: bool):
-    """One block's partial margins over its row groups, in row order."""
-    parts = [
-        _block_margins(idx, val, w_block, use_kernels)
-        for idx, val in zip(groups.indices, groups.values)
-    ]
-    if len(parts) == 1:
-        return parts[0]
-    return jnp.concatenate(parts)[groups.order]
-
-
-def _group_scatter(groups: RowGroups, coeffs, block_dim: int):
-    """sum_i coeffs_i * x^(l)_i over one block's row groups, ``coeffs``
-    in row order; one group is exactly :func:`local_scatter`."""
-    if len(groups.indices) == 1:
-        return local_scatter(groups.indices[0], groups.values[0], coeffs,
-                             block_dim)
-    z = jnp.zeros((block_dim,), dtype=groups.values[0].dtype)
-    for idx, val, rows in zip(groups.indices, groups.values, groups.rows):
-        z = z.at[idx.reshape(-1)].add((val * coeffs[rows][:, None]).reshape(-1))
-    return z
-
-
 @functools.partial(
     jax.jit, static_argnames=("loss_name", "block_dims", "use_kernels")
 )
@@ -185,7 +155,7 @@ def _full_grad_blocks(
     bounds = _bounds(block_dims)
     with jax.named_scope("full_grad/margins"):
         parts = [
-            _group_margins(
+            group_margins(
                 block_groups[l],
                 jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
                 use_kernels,
@@ -196,7 +166,7 @@ def _full_grad_blocks(
     with jax.named_scope("full_grad/scatter"):
         coeffs = loss.dvalue(s0, labels) / labels.shape[0]
         z_blocks = [
-            _group_scatter(block_groups[l], coeffs, block_dims[l])
+            group_scatter(block_groups[l], coeffs, block_dims[l])
             for l in range(q)
         ]
     z_data = jnp.concatenate(z_blocks) if q > 1 else z_blocks[0]
@@ -307,7 +277,7 @@ def _inner_epoch(
             rows = [(block_indices[l][ids], block_values[l][ids])
                     for l in range(q)]
             parts = [
-                _block_margins(
+                block_margins(
                     rows[l][0],
                     rows[l][1],
                     jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
@@ -561,7 +531,7 @@ def _lazy_inner_epoch(
         # materialized — so coef is bit-identical to the eager epoch's.
         with jax.named_scope("inner/gather"):
             parts = [
-                _block_margins(rows[l][0], rows[l][1], w_blocks[l],
+                block_margins(rows[l][0], rows[l][1], w_blocks[l],
                                use_kernels)
                 for l in range(q)
             ]
@@ -751,17 +721,17 @@ def run_fdsvrg(
 
 @functools.partial(jax.jit, static_argnames=("use_kernels",))
 def _sim_margins(idx, val, w_block, use_kernels):
-    return _block_margins(idx, val, w_block, use_kernels)
+    return block_margins(idx, val, w_block, use_kernels)
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernels",))
 def _sim_full_margins(groups, w_block, use_kernels):
-    return _group_margins(groups, w_block, use_kernels)
+    return group_margins(groups, w_block, use_kernels)
 
 
 @functools.partial(jax.jit, static_argnames=("block_dim",))
 def _sim_scatter(groups, coeffs, block_dim):
-    return _group_scatter(groups, coeffs, block_dim)
+    return group_scatter(groups, coeffs, block_dim)
 
 
 @functools.partial(

@@ -4,11 +4,14 @@ This is the TPU-native realization of Algorithm 1, built on
 :class:`repro.dist.ShardMapBackend`.  The parameter vector ``w`` lives
 feature-sharded across the given mesh axes (every chip is one of the
 paper's Workers); the instance data arrives in the block-local sharded
-layout (:meth:`repro.data.block_csr.BlockCSR.stacked`): a ``[q, N, B]``
-stack of per-block re-indexed padded rows, sharded on the leading axis,
-so each worker holds only its own block's entries with LOCAL feature ids
-and ``B ≈ nnz_max / q``.  That is the paper's construction verbatim —
-worker l stores the feature *slice* of every instance.
+layout (:meth:`repro.data.block_csr.BlockCSR.on_mesh`): a ``[q*N, B]``
+row stack of per-block re-indexed padded rows, its rows split over the
+feature axes, so each worker holds only its own block's entries with
+LOCAL feature ids and ``B ≈ nnz_max / q``.  That is the paper's
+construction verbatim — worker l stores the feature *slice* of every
+instance.  The full gradient reads each block's rows grouped by length
+class (:class:`repro.data.block_csr.RowGroups`), which have one shape on
+every chip; the inner epoch samples rows of the slab.
 
 Communication per inner step is exactly one all-reduce of ``u`` scalars
 over the feature axes — the hardware tree standing in for Figure 5.  The
@@ -49,6 +52,7 @@ simulation driver (asserted in tests).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax
@@ -63,8 +67,15 @@ from repro.core.driver import (
     resolve_init_w,
     run_outer_loop,
 )
-from repro.core.partition import balanced
-from repro.data.block_csr import BlockCSR, local_margins, local_scatter
+from repro.core.partition import FeaturePartition, balanced
+from repro.data.block_csr import (
+    BlockCSR,
+    RowGroups,
+    block_margins,
+    group_margins,
+    group_scatter,
+    local_scatter,
+)
 from repro.dist import COSTS, ClusterModel, ShardMapBackend
 from repro.kernels import ops
 
@@ -122,23 +133,35 @@ def _resolve_backend(
     return backend, cfg.dim // q
 
 
-def _margin_of(cfg: FDSVRGShardedConfig, w_b, idx, val):
-    if cfg.use_kernels:
-        return ops.sparse_margins(idx, val, w_b)
-    return local_margins(idx, val, w_b)
-
-
-def _fullgrad_blk(cfg, backend, loss, block, w_blk, bidx, bval, labels):
-    """Full-gradient phase on one worker (Alg 1 lines 3-5): one N-vector
-    all-reduce, then a purely block-local scatter."""
+def _fullgrad_blk(cfg, backend, loss, block, w_blk, groups: RowGroups, labels):
+    """Full-gradient phase on one worker (Alg 1 lines 3-5) over its
+    block's row groups: one N-vector all-reduce of the partial margins,
+    then a purely block-local scatter."""
     with jax.named_scope("full_grad/margins"):
-        partial = _margin_of(cfg, w_blk, bidx, bval)
+        partial = group_margins(groups, w_blk, cfg.use_kernels)
     with jax.named_scope("full_grad/reduce"):
         s0 = backend.device_all_reduce(partial)
     with jax.named_scope("full_grad/scatter"):
         coeffs = loss.dvalue(s0, labels) / labels.shape[0]
-        z_blk = local_scatter(bidx, bval, coeffs, block)
+        z_blk = group_scatter(groups, coeffs, block)
     return z_blk, s0
+
+
+def _slab_groups(bidx, bval) -> RowGroups:
+    """A worker's slab as its one row group (every row in one class)."""
+    ident = jnp.arange(bidx.shape[0], dtype=jnp.int32)
+    return RowGroups((bidx,), (bval,), (ident,), ident)
+
+
+def _groups_spec(groups: RowGroups, axes) -> RowGroups:
+    """shard_map specs of a mesh-placed RowGroups: each group's rows
+    split over the feature axes, ``rows`` and ``order`` replicated."""
+    return RowGroups(
+        indices=tuple(P(axes, None) for _ in groups.indices),
+        values=tuple(P(axes, None) for _ in groups.values),
+        rows=tuple(P() for _ in groups.rows),
+        order=P(),
+    )
 
 
 def _inner_scan_blk(cfg, backend, loss, reg, block,
@@ -152,7 +175,7 @@ def _inner_scan_blk(cfg, backend, loss, reg, block,
             idx = bidx[ids]
             val = bval[ids]
             y = labels[ids]
-            partial = _margin_of(cfg, w_b, idx, val)
+            partial = block_margins(idx, val, w_b, cfg.use_kernels)
         # The per-step all-reduce of u partial margins.
         with jax.named_scope("inner/reduce"):
             s_m = backend.device_all_reduce(partial)
@@ -181,28 +204,32 @@ def make_fullgrad(
     feature_axes: Sequence[str] = ("data", "model"),
     backend: ShardMapBackend | None = None,
 ):
-    """Build the jittable snapshot half: ``(w, block_indices,
-    block_values, labels) -> (z, s0)`` with ``z`` feature-sharded like
-    ``w`` and ``s0`` (the margins at ``w``) replicated.  This is the
-    harness ``snapshot`` hook — its output rotates into the next epoch
-    AND carries the same-iterate reporting pair."""
+    """Build the jittable snapshot half: ``(w, groups, labels) -> (z,
+    s0)`` with ``groups`` the mesh-placed row groups
+    (:meth:`BlockCSR.on_mesh`), ``z`` feature-sharded like ``w`` and
+    ``s0`` (the margins at ``w``) replicated.  This is the harness
+    ``snapshot`` hook — its output rotates into the next epoch AND
+    carries the same-iterate reporting pair.  One program is traced per
+    group structure."""
     backend, block = _resolve_backend(mesh, cfg, feature_axes, backend)
     loss = losses_lib.LOSSES[cfg.loss_name]
     axes = backend.feature_axes
 
-    def worker(w_blk, bidx, bval, labels):
-        z_blk, s0 = _fullgrad_blk(
-            cfg, backend, loss, block, w_blk, bidx[0], bval[0], labels
-        )
-        return z_blk, s0
+    def worker(w_blk, groups, labels):
+        return _fullgrad_blk(cfg, backend, loss, block, w_blk, groups, labels)
 
-    spec_rows = P(axes, None, None)
-    mapped = backend.shard_map(
-        worker,
-        in_specs=(P(axes), spec_rows, spec_rows, P(None)),
-        out_specs=(P(axes), P(None)),
-    )
-    return jax.jit(mapped)
+    # The program's name (XLA module ``jit_mesh_full_grad``) is what
+    # the benchmark's trace readers look for.
+    @jax.jit
+    def mesh_full_grad(w, groups, labels):
+        mapped = backend.shard_map(
+            worker,
+            in_specs=(P(axes), _groups_spec(groups, axes), P(None)),
+            out_specs=(P(axes), P(None)),
+        )
+        return mapped(w, groups, labels)
+
+    return mesh_full_grad
 
 
 def make_inner_epoch(
@@ -213,7 +240,8 @@ def make_inner_epoch(
 ):
     """Build the jittable epoch half: ``(w, z, s0, block_indices,
     block_values, labels, samples) -> w_next`` — the M-step inner scan
-    consuming a snapshot produced by :func:`make_fullgrad`."""
+    consuming a snapshot produced by :func:`make_fullgrad`, over the
+    ``[q*N, B]`` row-stacked slabs."""
     backend, block = _resolve_backend(mesh, cfg, feature_axes, backend)
     loss = losses_lib.LOSSES[cfg.loss_name]
     reg = losses_lib.Regularizer(cfg.reg_name, cfg.lam, cfg.lam2)
@@ -222,17 +250,23 @@ def make_inner_epoch(
     def worker(w_blk, z_blk, s0, bidx, bval, labels, samples):
         return _inner_scan_blk(
             cfg, backend, loss, reg, block,
-            w_blk, z_blk, s0, bidx[0], bval[0], labels, samples,
+            w_blk, z_blk, s0, bidx, bval, labels, samples,
         )
 
-    spec_rows = P(axes, None, None)
+    spec_rows = P(axes, None)
     mapped = backend.shard_map(
         worker,
         in_specs=(P(axes), P(axes), P(None), spec_rows, spec_rows,
                   P(None), P(None, None)),
         out_specs=P(axes),
     )
-    return jax.jit(mapped)
+
+    # XLA module ``jit_mesh_inner_epoch``, as the trace readers know it.
+    @jax.jit
+    def mesh_inner_epoch(w, z, s0, bidx, bval, labels, samples):
+        return mapped(w, z, s0, bidx, bval, labels, samples)
+
+    return mesh_inner_epoch
 
 
 def make_outer_iteration(
@@ -248,8 +282,8 @@ def make_outer_iteration(
         -> (w_next, full_grad_norm)
     with shardings:
       w:             P(feature_axes)        (feature-distributed, the paper)
-      block_indices: P(feature_axes, None, None)  int32[q, N, B] local ids
-      block_values:  P(feature_axes, None, None)  float[q, N, B]
+      block_indices: P(feature_axes, None)  int32[q*N, B] local ids
+      block_values:  P(feature_axes, None)  float[q*N, B]
       labels:        P(None)
       samples:       P(None, None)          int32[M, u]
 
@@ -257,7 +291,8 @@ def make_outer_iteration(
     iterate (the full-gradient phase computes it for free); the harness
     driver (:func:`run_fdsvrg_sharded`) reports post-epoch residuals
     instead, via the split :func:`make_fullgrad` / :func:`make_inner_epoch`
-    pair.  Build the data stack once with
+    pair.  Its full gradient reads the slab as one row group (the AOT
+    shape of uniform rows).  Build the data stack once with
     ``BlockCSR.from_padded(data, balanced(dim, q)).stacked()``.
     """
     backend, block = _resolve_backend(mesh, cfg, feature_axes, backend)
@@ -266,10 +301,8 @@ def make_outer_iteration(
     axes = backend.feature_axes
 
     def worker(w_blk, bidx, bval, labels, samples):
-        bidx = bidx[0]  # [N, B]: the leading q-axis shards to size 1
-        bval = bval[0]
         z_blk, s0 = _fullgrad_blk(
-            cfg, backend, loss, block, w_blk, bidx, bval, labels
+            cfg, backend, loss, block, w_blk, _slab_groups(bidx, bval), labels
         )
         gnorm_sq = jax.lax.psum(
             jnp.sum(_opt_residual_blk(reg, cfg.eta, w_blk, z_blk) ** 2), axes
@@ -281,7 +314,7 @@ def make_outer_iteration(
         return w_blk, gnorm_sq
 
     spec_w = P(axes)
-    spec_rows = P(axes, None, None)
+    spec_rows = P(axes, None)
     mapped = backend.shard_map(
         worker,
         in_specs=(spec_w, spec_rows, spec_rows, P(None), P(None, None)),
@@ -294,6 +327,23 @@ def make_outer_iteration(
         return w_next, jnp.sqrt(gnorm_sq)
 
     return outer_iteration
+
+
+def mesh_partition(dim: int, q: int) -> FeaturePartition:
+    """The mesh's partition of ``dim`` features: q blocks of one size,
+    ``dim`` padded with zero columns up to the next multiple of q."""
+    return balanced(-(-dim // q) * q, q)
+
+
+@functools.lru_cache(maxsize=8)
+def _steps(mesh: Mesh, cfg: FDSVRGShardedConfig, axes: tuple[str, ...]):
+    """The compiled full-gradient and inner-epoch halves for one mesh
+    and configuration, built once: a warm-started ``solve()`` call with
+    the same shapes traces nothing again."""
+    backend = ShardMapBackend(mesh=mesh, feature_axes=axes,
+                              tree_mode=cfg.tree_mode)
+    return (make_fullgrad(mesh, cfg, axes, backend=backend),
+            make_inner_epoch(mesh, cfg, axes, backend=backend))
 
 
 def run_fdsvrg_sharded(
@@ -309,16 +359,24 @@ def run_fdsvrg_sharded(
 ):
     """Metered driver for the deployable path, on the shared harness.
 
-    Re-indexes ``data`` (a PaddedCSR) into the block-local stacked layout
-    for the mesh's q workers and runs ``outer_iters`` iterations of the
-    split :func:`make_fullgrad` / :func:`make_inner_epoch` pair through
+    ``data`` is a :class:`~repro.data.block_csr.BlockCSR` on
+    :func:`mesh_partition` (``BlockCSR.from_blocks`` of blocks made one
+    per device, or ``from_padded``), or a PaddedCSR, which is
+    re-indexed here.  The layout is placed on the mesh once
+    (:meth:`BlockCSR.on_mesh`, kept with the layout) and runs
+    ``outer_iters`` iterations of the split :func:`make_fullgrad` /
+    :func:`make_inner_epoch` pair through
     :func:`repro.core.driver.run_outer_loop` — so snapshot rotation,
     sample drawing (same rng stream as :func:`repro.core.fdsvrg.run_fdsvrg`
     at the same seed), and same-iterate objective/optimality reporting
     are the engine's, not a local copy.  Traffic and modeled time are
     charged from the shared closed forms (:data:`repro.dist.COSTS`), so
     the meter is bit-consistent with the simulation driver's for the same
-    shapes (asserted in tests).
+    shapes (asserted in tests).  Under the profiler each full-gradient
+    dispatch adds the groups' lanes over all chips and the stored
+    entries to ``full_grad.lanes`` / ``full_grad.stored``, and each
+    epoch its M all-reduces to ``mesh.allreduce_steps``
+    (:mod:`repro.obs`).
 
     A ``dim`` that q does not divide is padded with zero feature
     columns up to the next multiple of q.  No row stores them, so their
@@ -338,22 +396,44 @@ def run_fdsvrg_sharded(
     )
     q = backend.q
     dim = cfg.dim
-    padded = -(-dim // q) * q
-    if padded != dim:
-        cfg = dataclasses.replace(cfg, dim=padded)
-        data = dataclasses.replace(data, dim=padded)
-        if init_w is not None:
-            init_w = jnp.pad(jnp.asarray(init_w), (0, padded - dim))
-    fullgrad = make_fullgrad(mesh, cfg, feature_axes, backend=backend)
-    inner_epoch = make_inner_epoch(mesh, cfg, feature_axes, backend=backend)
-    block_data = BlockCSR.from_padded(data, balanced(cfg.dim, q))
-    bidx, bval = block_data.stacked()
+    partition = mesh_partition(dim, q)
+    if isinstance(data, BlockCSR):
+        if data.partition != partition or data.dim != dim:
+            raise ValueError(
+                f"the layout covers dim={data.dim} in blocks "
+                f"{data.partition.bounds}; the mesh of {q} needs dim={dim} "
+                f"in {partition.bounds}"
+            )
+        block_data = data
+    else:
+        block_data = BlockCSR.from_padded(data, partition)
+    cfg = dataclasses.replace(cfg, dim=partition.dim)
+    # One placement whatever the caller's, so that a call warm-started
+    # from a returned (replicated) iterate runs the programs a cold one
+    # compiled, and traces nothing.
+    w0 = jax.device_put(
+        resolve_init_w(init_w, dim, block_data.values[0].dtype),
+        NamedSharding(mesh, P()),
+    )
+    if partition.dim != dim:
+        w0 = jnp.pad(w0, (0, partition.dim - dim))
+    w0 = jax.device_put(w0, NamedSharding(mesh, P(tuple(feature_axes))))
+    _resolve_backend(mesh, cfg, feature_axes, backend)
+    fullgrad, inner_epoch = _steps(
+        mesh, dataclasses.replace(cfg, tree_mode=backend.tree_mode),
+        backend.feature_axes,
+    )
+    placed = block_data.on_mesh(mesh, backend.feature_axes)
+    bidx, bval, labels = placed.indices, placed.values, placed.labels
+    lanes = placed.groups.lanes
     loss = losses_lib.LOSSES[cfg.loss_name]
     reg = losses_lib.Regularizer(cfg.reg_name, cfg.lam, cfg.lam2)
     n, nnz, u = cfg.num_instances, cfg.nnz_max, cfg.batch_size
 
     def snapshot(w):
-        return fullgrad(w, bidx, bval, data.labels)
+        obs.count("full_grad.lanes", lanes)
+        obs.count("full_grad.stored", block_data.stored)
+        return fullgrad(w, placed.groups, labels)
 
     def epoch(t, rng, w, z_data, s0):
         backend.meter_tree(payload=n)
@@ -361,7 +441,8 @@ def run_fdsvrg_sharded(
         with obs.span("outer.samples"):
             samples = jnp.asarray(draw_samples(rng, n, cfg.inner_steps, u))
         with obs.span("outer.epoch"):
-            w = inner_epoch(w, z_data, s0, bidx, bval, data.labels, samples)
+            obs.count("mesh.allreduce_steps", cfg.inner_steps)
+            w = inner_epoch(w, z_data, s0, bidx, bval, labels, samples)
         backend.meter_tree(payload=u, steps=cfg.inner_steps)
         backend.charge_cost(
             COSTS.fd_inner_step(nnz=nnz, q=q, u=u), steps=cfg.inner_steps
@@ -371,10 +452,10 @@ def run_fdsvrg_sharded(
     result = run_outer_loop(
         outer_iters=outer_iters,
         seed=seed,
-        init_w=resolve_init_w(init_w, cfg.dim, data.values.dtype),
+        init_w=w0,
         snapshot=snapshot,
         epoch=epoch,
-        evaluate=make_same_iterate_eval(data.labels, loss, reg, cfg.eta),
+        evaluate=make_same_iterate_eval(labels, loss, reg, cfg.eta),
         backend=backend,
     )
     w = jax.device_put(result.w, NamedSharding(mesh, P()))
@@ -385,8 +466,8 @@ def input_shardings(mesh: Mesh, feature_axes: Sequence[str] = ("data", "model"))
     axes = tuple(feature_axes)
     return (
         NamedSharding(mesh, P(axes)),
-        NamedSharding(mesh, P(axes, None, None)),
-        NamedSharding(mesh, P(axes, None, None)),
+        NamedSharding(mesh, P(axes, None)),
+        NamedSharding(mesh, P(axes, None)),
         NamedSharding(mesh, P(None)),
         NamedSharding(mesh, P(None, None)),
     )

@@ -44,7 +44,7 @@ import tempfile
 
 import numpy as np
 
-from repro.data.block_csr import BlockCSR, row_groups
+from repro.data.block_csr import BlockCSR, block_groups
 from repro.data.pipeline import (
     DEFAULT_CHUNK_ROWS,
     DataSource,
@@ -165,7 +165,7 @@ def load_block_csr(
     ):
         return None  # key collision or stale format: rebuild, don't trust
     q = partition.num_blocks
-    block_indices, block_values, block_nnz_col, groups = [], [], [], []
+    block_indices, block_values, block_nnz_col = [], [], []
     stored = 0
     for l in range(q):
         slab_path = os.path.join(entry, f"slab_{l:04d}.npz")
@@ -183,8 +183,6 @@ def load_block_csr(
             block_indices.append(jnp.asarray(indices))
             block_values.append(jnp.asarray(values))
             block_nnz_col.append(jnp.asarray(slab["nnz_col"]))
-            groups.append(row_groups(
-                indices, values, (block_indices[-1], block_values[-1])))
             stored += int(slab["nnz_col"].sum())
     labels = np.load(os.path.join(entry, "labels.npy"))
     return BlockCSR(
@@ -196,7 +194,7 @@ def load_block_csr(
         nnz_col=tuple(block_nnz_col),
         nnz_max=int(manifest["nnz_max"]),
         stored=stored,
-        groups=tuple(groups),
+        groups=block_groups(list(zip(block_indices, block_values))),
     )
 
 

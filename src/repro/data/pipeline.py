@@ -45,7 +45,7 @@ from typing import Iterator
 import numpy as np
 
 from repro.data import libsvm as libsvm_lib
-from repro.data.block_csr import BlockCSR, _count_cols, row_groups
+from repro.data.block_csr import BlockCSR, _count_cols, block_groups
 from repro.data.sparse import PaddedCSR
 
 #: Default rows-per-chunk budget; at news20-like widths (~500 stored
@@ -516,15 +516,13 @@ def _assemble(partition, acc, labels_parts, stats, lane_multiple, source):
     import jax.numpy as jnp
 
     q = partition.num_blocks
-    block_indices, block_values, block_nnz_col, groups = [], [], [], []
+    block_indices, block_values, block_nnz_col = [], [], []
     stored = 0
     for l in range(q):
         idx, val, nnz_col = acc[l].finalize(lane_multiple)
         block_indices.append(jnp.asarray(idx))
         block_values.append(jnp.asarray(val))
         block_nnz_col.append(jnp.asarray(nnz_col))
-        groups.append(
-            row_groups(idx, val, (block_indices[-1], block_values[-1])))
         stored += int(nnz_col.sum())
     labels = (
         np.concatenate(labels_parts)
@@ -545,7 +543,7 @@ def _assemble(partition, acc, labels_parts, stats, lane_multiple, source):
         nnz_col=tuple(block_nnz_col),
         nnz_max=stats.nnz_max,
         stored=stored,
-        groups=tuple(groups),
+        groups=block_groups(list(zip(block_indices, block_values))),
     )
 
 

@@ -317,8 +317,8 @@ def dryrun_fdsvrg(multi_pod: bool) -> dict:
 
     bnnz = aot_nnz_budget(nnz, q)  # block-local stacked rows, nnz/q + skew slack
     w = jax.ShapeDtypeStruct((d_pad,), jnp.float32)
-    idx = jax.ShapeDtypeStruct((q, n, bnnz), jnp.int32)
-    val = jax.ShapeDtypeStruct((q, n, bnnz), jnp.float32)
+    idx = jax.ShapeDtypeStruct((q * n, bnnz), jnp.int32)
+    val = jax.ShapeDtypeStruct((q * n, bnnz), jnp.float32)
     lab = jax.ShapeDtypeStruct((n,), jnp.float32)
     samples = jax.ShapeDtypeStruct((m, u), jnp.int32)
     t0 = time.time()

@@ -117,12 +117,14 @@ def test_stacked_uniform_budget_and_equivalence():
     part = balanced(data.dim, q)
     b = BlockCSR.from_padded(data, part)
     sidx, sval = b.stacked()
-    assert sidx.shape == sval.shape == (q, data.num_instances, max(b.nnz_budgets))
+    n = data.num_instances
+    assert sidx.shape == sval.shape == (q * n, max(b.nnz_budgets))
     w = jnp.asarray(RNG.normal(size=data.dim).astype(np.float32))
-    total = jnp.zeros((data.num_instances,), jnp.float32)
+    total = jnp.zeros((n,), jnp.float32)
     for l in range(q):
         lo, hi = part.block(l)
-        total = total + local_margins(sidx[l], sval[l], w[lo:hi])
+        rows = slice(l * n, (l + 1) * n)
+        total = total + local_margins(sidx[rows], sval[rows], w[lo:hi])
     np.testing.assert_allclose(
         np.asarray(total), np.asarray(margins(data, w)), rtol=2e-4, atol=1e-5
     )

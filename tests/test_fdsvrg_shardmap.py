@@ -5,8 +5,8 @@ a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8 (the
 main test process must keep seeing exactly 1 device).
 
 The shard_map path consumes the block-local stacked layout
-(BlockCSR.stacked): [q, N, B] re-indexed rows sharded over the feature
-axes, so workers never see global ids.
+(BlockCSR.stacked / on_mesh): [q*N, B] re-indexed rows split over the
+feature axes, so workers never see global ids.
 """
 
 import os

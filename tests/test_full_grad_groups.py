@@ -18,14 +18,13 @@ import pytest
 from repro.core import losses
 from repro.core.fdsvrg import (
     SVRGConfig,
-    _block_margins,
     _bounds,
     _full_grad_blocks,
     fdsvrg_worker_simulation,
     run_fdsvrg,
 )
 from repro.core.partition import balanced
-from repro.data.block_csr import GROUP_LANES, BlockCSR, local_scatter
+from repro.data.block_csr import GROUP_LANES, BlockCSR, block_margins, local_scatter
 from repro.data.pipeline import ArraySource, stream_block_csr
 from repro.data.sparse import PaddedCSR
 from repro.dist import SimBackend, tree_order_sum
@@ -73,7 +72,7 @@ def _padded_full_grad(block_indices, block_values, labels, w, block_dims,
     row groups: every lane of every row gathered and scattered."""
     bounds = _bounds(block_dims)
     s0 = tree_order_sum([
-        _block_margins(block_indices[l], block_values[l],
+        block_margins(block_indices[l], block_values[l],
                        w[bounds[l]:bounds[l + 1]], use_kernels)
         for l in range(len(block_dims))
     ])
