@@ -12,11 +12,13 @@ classifier.  Any registered method is a constructor argument away —
 through the same :func:`repro.api.solve` front door the benchmarks use.
 
 ``partial_fit`` warm-starts from the current coefficients via the
-harness's snapshot rotation: the outer-loop engine computes the full
-gradient at ``init_w`` before the first epoch, so continuing a run is
-exactly "one more rotation" of the same machinery — no cold restart, no
-re-deriving state.  Each call advances the seed so the sample stream
-does not replay.
+harness's snapshot rotation.  ``coef_`` keeps the returned iterate's
+bits, so :func:`repro.api.solve` hands the next call the full gradient
+and margins the last one ended on (:data:`repro.api.cache.SNAPSHOT_SLOT`)
+and the outer-loop engine starts from them: continuing a run on the same
+data is exactly "one more rotation" of the same machinery — no cold
+restart, no full gradient recomputed at the coefficients.  Each call
+advances the seed so the sample stream does not replay.
 """
 
 from __future__ import annotations

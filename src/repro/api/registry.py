@@ -16,6 +16,11 @@ front door that
   mesh on a non-shard_map method, Option II on a driver that ignores it),
 * owns partition building and BlockCSR caching (the shared bounded
   :data:`repro.api.cache.BLOCK_CACHE`),
+* carries the last call's final snapshot into the next one that is
+  warm-started from the iterate it returned
+  (:data:`repro.api.cache.SNAPSHOT_SLOT`; ``serial``, ``fdsvrg`` and
+  ``fdsvrg_sharded``), so a continued run computes one full gradient
+  an outer, not one more,
 * dispatches to the registered driver and returns its
   :class:`~repro.core.driver.RunResult` — the same history schema for
   every method, so callers compare like-for-like.
@@ -49,7 +54,7 @@ from typing import Callable
 import jax
 
 from repro import obs
-from repro.api.cache import BLOCK_CACHE
+from repro.api.cache import BLOCK_CACHE, SNAPSHOT_SLOT
 from repro.api.spec import PAPER, ExperimentSpec
 from repro.core import baselines
 from repro.core import losses as losses_lib
@@ -320,6 +325,7 @@ def _solve(spec: ExperimentSpec) -> RunResult:
             spec.data if spec.data is not None else _load_dataset(spec.dataset)
         )
         n = data.num_instances
+    SNAPSHOT_SLOT.scope(data)
     mesh = None
     if info.needs_mesh:
         mesh = spec.mesh
@@ -391,6 +397,16 @@ def _checkpoint_policy(spec: ExperimentSpec) -> CheckpointPolicy | None:
     )
 
 
+def _carried(spec: ExperimentSpec, data, layout, run: Callable) -> RunResult:
+    """``run(start)`` with the snapshot the last call left, when this
+    call continues from the iterate it returned on the same data and
+    ``layout``, and keep this call's for the next.  The key holds what
+    decides the snapshot's bits beside the data and the iterate."""
+    key = (spec.method, spec.loss, spec.use_kernels, spec.tree_mode, layout)
+    start = SNAPSHOT_SLOT.take(data, key, spec.init_w)
+    return SNAPSHOT_SLOT.put(data, key, run(start))
+
+
 def _source_slabs(spec: ExperimentSpec, source, q: int):
     """Streamed per-worker slabs for a source= run, through both cache
     layers (in-process identity cache; on-disk when the spec names one)."""
@@ -410,17 +426,17 @@ def _source_slabs(spec: ExperimentSpec, source, q: int):
     summary="Algorithm 2 (serial SVRG), the proof reference",
 )
 def _solve_serial(spec, data, p, mesh) -> RunResult:
-    block = None
+    block, padded = None, data
     if is_source(data):
         # Serial runs on the q=1 layout whatever spec.q says (q only
         # shapes the FD partitions).
-        block, data = _source_slabs(spec, data, 1), None
-    return run_serial_svrg(
-        data, losses_lib.LOSSES[spec.loss], spec.reg, _svrg_config(spec, p),
+        block, padded = _source_slabs(spec, data, 1), None
+    return _carried(spec, data, 1, lambda start: run_serial_svrg(
+        padded, losses_lib.LOSSES[spec.loss], spec.reg, _svrg_config(spec, p),
         use_kernels=spec.use_kernels, lazy_updates=spec.lazy_updates,
         block_data=block,
-        init_w=spec.init_w, checkpoint=_checkpoint_policy(spec),
-    )
+        init_w=spec.init_w, checkpoint=_checkpoint_policy(spec), start=start,
+    ))
 
 
 @register_method(
@@ -432,16 +448,16 @@ def _solve_serial(spec, data, p, mesh) -> RunResult:
 )
 def _solve_fdsvrg(spec, data, p, mesh) -> RunResult:
     if is_source(data):
-        block, data = _source_slabs(spec, data, p.q), None
+        block, padded = _source_slabs(spec, data, p.q), None
     else:
-        block = BLOCK_CACHE.get(data, p.q)
-    return run_fdsvrg(
-        data, block.partition, losses_lib.LOSSES[spec.loss], spec.reg,
+        block, padded = BLOCK_CACHE.get(data, p.q), data
+    return _carried(spec, data, block.partition.bounds, lambda start: run_fdsvrg(
+        padded, block.partition, losses_lib.LOSSES[spec.loss], spec.reg,
         _svrg_config(spec, p), spec.cluster,
         use_kernels=spec.use_kernels, lazy_updates=spec.lazy_updates,
         block_data=block,
-        init_w=spec.init_w, checkpoint=_checkpoint_policy(spec),
-    )
+        init_w=spec.init_w, checkpoint=_checkpoint_policy(spec), start=start,
+    ))
 
 
 @register_method(
@@ -481,12 +497,13 @@ def _solve_fdsvrg_sharded(spec, data, p, mesh) -> RunResult:
     # A BlockCSR (BlockCSR.from_blocks: blocks made one per device) runs
     # as it is; a PaddedCSR is re-indexed once per data set into the
     # mesh's padded partition through the shared cache.
+    block = data
     if not isinstance(data, BlockCSR):
-        data = BLOCK_CACHE.get(data, p.q, mesh_partition(data.dim, p.q))
+        block = BLOCK_CACHE.get(data, p.q, mesh_partition(data.dim, p.q))
     cfg = FDSVRGShardedConfig(
-        dim=data.dim,
-        num_instances=data.num_instances,
-        nnz_max=data.global_nnz_max(),
+        dim=block.dim,
+        num_instances=block.num_instances,
+        nnz_max=block.global_nnz_max(),
         eta=p.eta,
         inner_steps=p.inner_steps,
         batch_size=p.batch_size,
@@ -496,11 +513,13 @@ def _solve_fdsvrg_sharded(spec, data, p, mesh) -> RunResult:
         lam2=spec.reg.lam2,
         tree_mode=spec.tree_mode,
     )
-    return run_fdsvrg_sharded(
-        data, mesh, cfg, feature_axes=tuple(mesh.axis_names),
+    # The carried arrays live on the mesh's devices, in its layout.
+    layout = (block.partition.bounds, mesh)
+    return _carried(spec, data, layout, lambda start: run_fdsvrg_sharded(
+        block, mesh, cfg, feature_axes=tuple(mesh.axis_names),
         outer_iters=spec.outer_iters, seed=spec.seed, cluster=spec.cluster,
-        init_w=spec.init_w,
-    )
+        init_w=spec.init_w, start=start,
+    ))
 
 
 def _register_baseline(name, runner, *, paper_eta, inner_rule, supports_option_ii=True, summary):

@@ -9,11 +9,14 @@ hooks and nothing else:
 
 ``snapshot(w) -> (z_data, s0)``
     The data part of the full gradient and the margins at ``w``,
-    **compute only** — never meters.  The harness calls it once before
-    the first epoch (the outer-0 snapshot) and once after every epoch:
-    the post-epoch full gradient doubles as the next outer's snapshot
-    AND as the same-iterate diagnostic pair for reporting, so the whole
-    run pays exactly one extra full gradient.
+    **compute only** — never meters.  The harness calls it once after
+    every epoch: the post-epoch full gradient doubles as the next
+    outer's snapshot AND as the same-iterate diagnostic pair for
+    reporting.  Before the first epoch it needs the pair at ``init_w``:
+    it takes the caller's ``init_snapshot`` (the :attr:`RunResult.final`
+    of a run that ended at ``init_w``) or a checkpoint's, and only
+    otherwise calls ``snapshot(init_w)`` — the one full gradient a run
+    pays beyond its outers.
 
 ``epoch(t, rng, w, z_data, s0) -> w``
     One outer iteration's inner work: draw samples (via
@@ -98,11 +101,27 @@ class OuterRecord:
     wall_time_s: float
 
 
+@dataclasses.dataclass(frozen=True)
+class Snapshot:
+    """The data part of the full gradient ``z`` and the margins ``s0`` at
+    the iterate ``w``, in the driver's own layout (on a mesh: ``w`` and
+    ``z`` padded and feature-sharded).  What the outer loop holds between
+    epochs, and all a later run needs to start at ``w`` without
+    computing the full gradient there again."""
+
+    w: jax.Array
+    z: jax.Array
+    s0: jax.Array
+
+
 @dataclasses.dataclass
 class RunResult:
     w: jax.Array
     history: list[OuterRecord]
     meter: CommMeter
+    # The pair the last rotation produced, at the final iterate: a run
+    # warm-started from it passes it back as ``init_snapshot``.
+    final: Snapshot | None = None
 
     def objectives(self) -> np.ndarray:
         return np.array([h.objective for h in self.history])
@@ -315,6 +334,16 @@ def _save_outer_state(
     )
 
 
+def _outer_state_like(policy: CheckpointPolicy, w) -> dict:
+    """Restore templates for (w, z, s0) with no snapshot computed: ``z``
+    has ``w``'s shape and dtype, ``s0`` the checkpoint's own row count
+    (which the first rotation then checks against the data's)."""
+    meta = ckpt.load_meta(policy.path)
+    s0_key = jax.tree_util.keystr((jax.tree_util.DictKey("s0"),))
+    s0_shape = dict(zip(meta["keys"], meta["shapes"]))[s0_key]
+    return {"w": w, "z": w, "s0": jax.ShapeDtypeStruct(tuple(s0_shape), w.dtype)}
+
+
 # ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
@@ -331,6 +360,7 @@ def run_outer_loop(
     backend: Collectives | None = None,
     recovery: RecoveryPolicy | None = None,
     checkpoint: CheckpointPolicy | None = None,
+    init_snapshot: tuple[jax.Array, jax.Array] | None = None,
 ) -> RunResult:
     """Run ``outer_iters`` outer iterations with snapshot rotation.
 
@@ -349,7 +379,12 @@ def run_outer_loop(
     backoff is threaded through it (a retried epoch reruns with a
     smaller step); hooks that don't accept it still get abort/retry.
     ``checkpoint`` arms persistence/resume (see
-    :class:`CheckpointPolicy`).
+    :class:`CheckpointPolicy`).  ``init_snapshot`` is ``(z, s0)`` at
+    ``init_w``, in the hooks' layout, as a run that ended at ``init_w``
+    returned it (:attr:`RunResult.final`); given one, or resuming, the
+    loop computes no full gradient before the first epoch.  Under the
+    profiler each run counts one of ``snapshot0.carried`` and
+    ``snapshot0.computed``.
 
     Under the profiler each phase is a :mod:`repro.obs` span:
     ``loop.snapshot0``, then per attempt an ``outer`` step span
@@ -366,12 +401,18 @@ def run_outer_loop(
     start_outer = 0
     accepts_scale = "eta_scale" in inspect.signature(epoch).parameters
     t_start = time.perf_counter()
+    resume = checkpoint is not None and checkpoint.resume and checkpoint.exists()
     with obs.span("loop.snapshot0"):
-        z_data, s0 = snapshot(w)  # outer-0 snapshot
-    if checkpoint is not None and checkpoint.resume and checkpoint.exists():
-        state = ckpt.restore(
-            checkpoint.path, {"w": w, "z": z_data, "s0": s0}
-        )
+        # The outer-0 snapshot: the checkpoint's or the caller's when
+        # there is one, else the full gradient at init_w.
+        if resume or init_snapshot is not None:
+            obs.count("snapshot0.carried", 1)
+            z_data, s0 = init_snapshot or (None, None)
+        else:
+            obs.count("snapshot0.computed", 1)
+            z_data, s0 = snapshot(w)
+    if resume:
+        state = ckpt.restore(checkpoint.path, _outer_state_like(checkpoint, w))
         extra = ckpt.load_meta(checkpoint.path)["extra"]
         w, z_data, s0 = state["w"], state["z"], state["s0"]
         rng.bit_generator.state = extra["rng_state"]
@@ -404,6 +445,13 @@ def run_outer_loop(
                     # the SAME iterate).
                     with obs.span("outer.snapshot"):
                         z_new, s0_new = snapshot(w_new)
+                    if s0_new.shape != s0.shape:
+                        raise ValueError(
+                            f"outer {t}: the margins have shape "
+                            f"{s0_new.shape}, the snapshot it started from "
+                            f"{s0.shape}: a checkpoint or carried snapshot "
+                            "of other data"
+                        )
                     with obs.span("outer.evaluate"):
                         obj, gnorm = evaluate(w_new, z_new, s0_new)
                     if recovery is not None:
@@ -467,4 +515,6 @@ def run_outer_loop(
                             history=history,
                         )
             break
-    return RunResult(w=w, history=history, meter=meter)
+    return RunResult(
+        w=w, history=history, meter=meter, final=Snapshot(w, z_data, s0)
+    )

@@ -54,6 +54,7 @@ from repro.core.driver import (
     OuterRecord,
     RecoveryPolicy,
     RunResult,
+    Snapshot,
     draw_samples,
     make_same_iterate_eval,
     objective_from_margins,
@@ -615,6 +616,7 @@ def run_serial_svrg(
     lazy_updates: str | None = None,
     recovery: RecoveryPolicy | None = None,
     checkpoint: CheckpointPolicy | None = None,
+    start: Snapshot | None = None,
 ) -> RunResult:
     _check_lazy(lazy_updates)
     if block_data is None:
@@ -640,6 +642,7 @@ def run_serial_svrg(
         init_w=init_w,
         recovery=recovery,
         checkpoint=checkpoint,
+        start=start,
     )
 
 
@@ -663,6 +666,7 @@ def run_fdsvrg(
     lazy_updates: str | None = None,
     recovery: RecoveryPolicy | None = None,
     checkpoint: CheckpointPolicy | None = None,
+    start: Snapshot | None = None,
 ) -> RunResult:
     """Algorithm 1 with q = partition.num_blocks feature-sharded workers.
 
@@ -683,6 +687,10 @@ def run_fdsvrg(
     ``lazy_updates`` ("exact" | "proba") swaps the inner epoch for the
     delayed-decay O(u * nnz_l) path (:func:`_lazy_inner_epoch`); it is
     block-local, so the metered schedule above is unchanged bit-for-bit.
+
+    ``start`` (the :attr:`~repro.core.driver.RunResult.final` of a run on
+    this layout) starts from its iterate and snapshot instead of
+    ``init_w``, computing no full gradient before the first epoch.
     """
     _check_lazy(lazy_updates)
     q = partition.num_blocks
@@ -711,6 +719,7 @@ def run_fdsvrg(
         init_w=init_w,
         recovery=recovery,
         checkpoint=checkpoint,
+        start=start,
     )
 
 

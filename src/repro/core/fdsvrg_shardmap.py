@@ -62,6 +62,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.core import losses as losses_lib
 from repro.core.driver import (
+    Snapshot,
     draw_samples,
     make_same_iterate_eval,
     resolve_init_w,
@@ -356,6 +357,7 @@ def run_fdsvrg_sharded(
     cluster: ClusterModel | None = None,
     backend: ShardMapBackend | None = None,
     init_w: jax.Array | None = None,
+    start: Snapshot | None = None,
 ):
     """Metered driver for the deployable path, on the shared harness.
 
@@ -388,7 +390,11 @@ def run_fdsvrg_sharded(
     every other driver, iterates in the data's dtype.  Its ``w`` is
     replicated over the mesh, so host-side code may index it whatever
     the mesh's axis types (a gather on an Explicit-sharded array needs
-    an output sharding the caller has no reason to know).
+    an output sharding the caller has no reason to know).  Its ``final``
+    holds the padded, feature-sharded iterate and the snapshot at it;
+    passed back as ``start``, a call on the same layout and mesh starts
+    there, with no full gradient and no re-placement of the iterate
+    before its first epoch (``init_w`` is then ignored).
     """
     backend = backend or ShardMapBackend(
         mesh=mesh, feature_axes=feature_axes,
@@ -408,16 +414,24 @@ def run_fdsvrg_sharded(
     else:
         block_data = BlockCSR.from_padded(data, partition)
     cfg = dataclasses.replace(cfg, dim=partition.dim)
-    # One placement whatever the caller's, so that a call warm-started
-    # from a returned (replicated) iterate runs the programs a cold one
-    # compiled, and traces nothing.
-    w0 = jax.device_put(
-        resolve_init_w(init_w, dim, block_data.values[0].dtype),
-        NamedSharding(mesh, P()),
-    )
-    if partition.dim != dim:
-        w0 = jnp.pad(w0, (0, partition.dim - dim))
-    w0 = jax.device_put(w0, NamedSharding(mesh, P(tuple(feature_axes))))
+    if start is not None:
+        if start.w.shape != (partition.dim,):
+            raise ValueError(
+                f"start holds an iterate of shape {start.w.shape}; the mesh "
+                f"layout runs ({partition.dim},)"
+            )
+        w0 = start.w
+    else:
+        # One placement whatever the caller's, so that a call
+        # warm-started from a returned (replicated) iterate runs the
+        # programs a cold one compiled, and traces nothing.
+        w0 = jax.device_put(
+            resolve_init_w(init_w, dim, block_data.values[0].dtype),
+            NamedSharding(mesh, P()),
+        )
+        if partition.dim != dim:
+            w0 = jnp.pad(w0, (0, partition.dim - dim))
+        w0 = jax.device_put(w0, NamedSharding(mesh, P(tuple(feature_axes))))
     _resolve_backend(mesh, cfg, feature_axes, backend)
     fullgrad, inner_epoch = _steps(
         mesh, dataclasses.replace(cfg, tree_mode=backend.tree_mode),
@@ -457,6 +471,7 @@ def run_fdsvrg_sharded(
         epoch=epoch,
         evaluate=make_same_iterate_eval(labels, loss, reg, cfg.eta),
         backend=backend,
+        init_snapshot=None if start is None else (start.z, start.s0),
     )
     w = jax.device_put(result.w, NamedSharding(mesh, P()))
     return dataclasses.replace(result, w=w[:dim])

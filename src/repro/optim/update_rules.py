@@ -55,6 +55,7 @@ from repro.core.driver import (
     CheckpointPolicy,
     RecoveryPolicy,
     RunResult,
+    Snapshot,
     draw_samples,
     make_same_iterate_eval,
     optimality_norm,
@@ -241,8 +242,14 @@ def run_with_rule(
     init_w=None,
     recovery: RecoveryPolicy | None = None,
     checkpoint: CheckpointPolicy | None = None,
+    start: Snapshot | None = None,
 ) -> RunResult:
-    """Wire one rule into the ONE outer-loop harness and run it."""
+    """Wire one rule into the ONE outer-loop harness and run it.
+
+    ``start`` is the :attr:`RunResult.final` of a run on the same layout
+    that ended where this one begins: its ``w`` is the starting iterate
+    and its pair the outer-0 snapshot, so no full gradient is computed
+    before the first epoch."""
     rule.validate(ctx)
     if recovery is not None and not rule.supports_recovery:
         raise ValueError(
@@ -265,13 +272,14 @@ def run_with_rule(
     return run_outer_loop(
         outer_iters=ctx.cfg.outer_iters,
         seed=ctx.cfg.seed,
-        init_w=rule.build_init_w(ctx, init_w),
+        init_w=rule.build_init_w(ctx, init_w if start is None else start.w),
         snapshot=rule.build_snapshot(ctx),
         epoch=rule.build_epoch(ctx),
         evaluate=rule.build_evaluate(ctx),
         backend=ctx.backend,
         recovery=recovery,
         checkpoint=checkpoint,
+        init_snapshot=None if start is None else (start.z, start.s0),
     )
 
 

@@ -19,13 +19,21 @@ Load-bearing properties:
 4. The bounded BlockCSR cache semantics (per-sweep scope + LRU), ported
    here from the benchmarks module along with the cache itself.
 5. ``FDSVRGClassifier`` fit/partial_fit(warm start)/predict/score.
+6. The carried snapshot (``repro.api.cache.SNAPSHOT_SLOT``): a call
+   warm-started from the iterate the last call returned, on the same
+   data, method, partition and loss, starts from that call's final
+   snapshot and runs bit for bit as one that computes it; any other
+   call computes it.
 """
+
+import gc
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.api import (
     BLOCK_CACHE,
     BlockCache,
@@ -37,6 +45,7 @@ from repro.api import (
     method_info,
     solve,
 )
+from repro.api.cache import SNAPSHOT_SLOT
 from repro.api.registry import _resolve
 from repro.core import baselines, losses
 from repro.core.driver import resolve_init_w
@@ -476,6 +485,106 @@ def test_warm_start_continues_from_given_iterate(data, method):
 
 
 # ---------------------------------------------------------------------------
+# 5b. the carried snapshot
+# ---------------------------------------------------------------------------
+
+
+def _counted(directory, fn):
+    """``fn()`` under the profiler, and the repro.obs counters it added."""
+    obs.reset()
+    with jax.profiler.trace(str(directory)):
+        out = fn()
+    counters = obs.totals()["counters"]
+    obs.reset()
+    return out, counters
+
+
+def _lanes(data, q):
+    bd = BLOCK_CACHE.get(data, q)
+    return sum(int(i.size) for g in bd.groups for i in g.indices)
+
+
+@pytest.mark.parametrize("method,q", [("serial", None), ("fdsvrg", 2),
+                                      ("fdsvrg", 4)])
+def test_warm_call_from_returned_iterate_equals_one_that_misses_the_slot(
+        tmp_path, data, method, q):
+    """From ``jax.block_until_ready(res.w)``, as the benchmark calls, the
+    next call carries the snapshot and computes one full gradient an
+    outer; from a copy of the same iterate after the slot moved on it
+    computes one more, and the two runs agree bit for bit."""
+    base = ExperimentSpec(method=method, data=data, reg=REG, q=q, eta=0.3,
+                          batch_size=2, inner_steps=8, outer_iters=2)
+    w = jax.block_until_ready(solve(base).w)
+    copy = jnp.asarray(np.asarray(w))
+    warm, carried = _counted(tmp_path / "warm", lambda: solve(
+        base.replace(init_w=w, seed=1)))
+    cold, computed = _counted(tmp_path / "cold", lambda: solve(
+        base.replace(init_w=copy, seed=1)))
+    lanes = _lanes(data, q or 1)
+    assert carried == {"snapshot0.carried": 1, "full_grad.lanes": 2 * lanes,
+                       "full_grad.stored": carried["full_grad.stored"]}
+    assert computed["snapshot0.computed"] == 1
+    assert computed["full_grad.lanes"] == 3 * lanes
+    assert "snapshot0.carried" not in computed
+    _assert_same_run(warm, cold)
+
+
+def _one_bit_off(w):
+    host = np.array(w)
+    host.view(np.uint32)[-1] ^= 1
+    return jnp.asarray(host)
+
+
+@pytest.mark.parametrize("change", [
+    "none", "other_data", "other_q", "other_loss", "other_method",
+    "one_bit_off", "no_init_w",
+])
+def test_slot_carries_only_the_returned_iterate_on_the_same_key(
+        tmp_path, data, change):
+    """The slot hands its snapshot only to a call whose init_w holds the
+    returned iterate's bits, on the same data object, method, partition
+    and loss; each other call computes the full gradient at init_w."""
+    base = ExperimentSpec(method="fdsvrg", data=data, reg=REG, q=2, eta=0.3,
+                          batch_size=2, inner_steps=8, outer_iters=1)
+    w = solve(base).w
+    same = PaddedCSR(data.indices, data.values, data.labels, data.dim)
+    spec = base.replace(**{"init_w": w, "seed": 1, **{
+        "none": {},
+        "other_data": {"data": same},
+        "other_q": {"q": 4},
+        "other_loss": {"loss": "squared_hinge"},
+        "other_method": {"method": "serial", "q": None},
+        "one_bit_off": {"init_w": _one_bit_off(w)},
+        "no_init_w": {"init_w": None},
+    }[change]})
+    _, counters = _counted(tmp_path, lambda: solve(spec))
+    hit = "snapshot0.carried" if change == "none" else "snapshot0.computed"
+    assert counters[hit] == 1
+    assert counters.get("snapshot0.carried", 0) + \
+        counters.get("snapshot0.computed", 0) == 1
+
+
+def test_slot_never_keeps_old_data_alive(data):
+    """A call on other data empties the slot, whatever its method, and so
+    does the collection of the data object it was filled on."""
+    base = ExperimentSpec(method="fdsvrg", data=data, reg=REG, q=2, eta=0.3,
+                          batch_size=2, inner_steps=8, outer_iters=1)
+    solve(base)
+    assert len(SNAPSHOT_SLOT) == 1
+    other = make_sparse_classification(dim=64, num_instances=16,
+                                       nnz_per_instance=4, seed=9)
+    solve(ExperimentSpec(method="dsvrg", data=other, reg=REG, q=2,
+                         inner_steps=4))
+    assert len(SNAPSHOT_SLOT) == 0
+    solve(base.replace(data=other))
+    assert len(SNAPSHOT_SLOT) == 1
+    BLOCK_CACHE.clear()
+    del other
+    gc.collect()
+    assert len(SNAPSHOT_SLOT) == 0
+
+
+# ---------------------------------------------------------------------------
 # 6. the estimator
 # ---------------------------------------------------------------------------
 
@@ -628,6 +737,23 @@ def test_estimator_multiclass_partial_fit_warm_starts():
     assert clf.coef_.shape == (3, X.shape[1])
     # warm start: the continued run's first outer beats the cold first
     assert clf.history_[2].objective < first
+
+
+@pytest.mark.parametrize("labels", ["binary", "multiclass"])
+def test_estimator_partial_fit_carries_the_snapshot(tmp_path, data, labels):
+    """coef_'s numpy round trip keeps the returned iterate's bits, so a
+    second partial_fit on the same data starts from the snapshot the
+    first one ended on."""
+    if labels == "binary":
+        X, y = data, None
+    else:
+        X, y = _three_blobs(seed=3)
+    clf = FDSVRGClassifier(method="fdsvrg", workers=2, eta=0.3, lam=1e-3,
+                           batch_size=2, inner_steps=8)
+    clf.partial_fit(X, y)
+    _, counters = _counted(tmp_path, lambda: clf.partial_fit(X, y))
+    assert counters["snapshot0.carried"] == 1
+    assert "snapshot0.computed" not in counters
 
 
 def test_estimator_single_class_raises():

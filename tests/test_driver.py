@@ -9,20 +9,24 @@ Load-bearing properties after the driver-drift refactor:
    A new driver or a edited closed form that drifts breaks this test, not
    a benchmark three PRs later.
 2. **Harness semantics** — snapshot rotation (one extra full gradient per
-   run, post-epoch z/w pairs), same-iterate reporting for every driver
-   including PS-Lite, and the shared rng-stream conventions.
+   run unless the pair at ``init_w`` is carried in or resumed, post-epoch
+   z/w pairs), same-iterate reporting for every driver including
+   PS-Lite, and the shared rng-stream conventions.
 3. Satellites: the `_inner_epoch` recompile fix (lam traced) and
    `use_kernels` plumbed through run_method.  (The BlockCSR cache tests
    moved to tests/test_api.py with the cache itself — it now lives in
    repro.api.cache.)
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import baselines, losses
 from repro.core.driver import (
+    CheckpointPolicy,
     OuterRecord,
     RunResult,
     optimality_norm,
@@ -271,6 +275,109 @@ def test_harness_rotates_snapshot_one_extra_full_gradient():
     assert [h.objective for h in res.history] == [1.0, 2.0, 3.0]
     assert isinstance(res, RunResult)
     assert res.meter.total_scalars == 0  # backend=None: fresh empty meter
+
+
+def _counted(directory, fn):
+    """``fn()`` under the profiler, and the repro.obs counters it added."""
+    obs.reset()
+    with jax.profiler.trace(str(directory)):
+        out = fn()
+    counters = obs.totals()["counters"]
+    obs.reset()
+    return out, counters
+
+
+@pytest.mark.parametrize("outers", [1, 3])
+@pytest.mark.parametrize("carried", [False, True], ids=["cold", "carried"])
+def test_harness_computes_snapshot0_only_without_a_carried_one(
+        tmp_path, carried, outers):
+    """Given the pair at init_w, a run of k outers takes k snapshots (one
+    after each epoch) and counts ``snapshot0.carried``; without it, k + 1
+    and ``snapshot0.computed``.  The first epoch consumes the pair it was
+    given, and ``final`` is the last rotation's, at the returned w."""
+    calls, seen = [], []
+
+    def snapshot(w):
+        calls.append(float(w[0]))
+        return 2.0 * w, w[:1]
+
+    def epoch(t, rng, w, z, s0):
+        seen.append((float(z[0]), float(s0[0])))
+        return w + 1.0
+
+    carry = (jnp.full((2,), 10.0), jnp.full((1,), 5.0)) if carried else None
+    res, counters = _counted(tmp_path, lambda: run_outer_loop(
+        outer_iters=outers, seed=0, init_w=jnp.full((2,), 5.0),
+        snapshot=snapshot, epoch=epoch,
+        evaluate=lambda w, z, s0: (float(w[0]), 0.0), init_snapshot=carry,
+    ))
+    after = [5.0 + t for t in range(1, outers + 1)]
+    assert calls == (after if carried else [5.0] + after)
+    assert seen == [(2.0 * (5.0 + t), 5.0 + t) for t in range(outers)]
+    assert counters == {
+        "snapshot0.carried" if carried else "snapshot0.computed": 1}
+    assert res.final.w is res.w
+    np.testing.assert_array_equal(np.asarray(res.final.z), 2.0 * np.asarray(res.w))
+    np.testing.assert_array_equal(np.asarray(res.final.s0), [5.0 + outers])
+
+
+def _lanes(bd):
+    return sum(int(i.size) for g in bd.groups for i in g.indices)
+
+
+@pytest.mark.parametrize("method", ["serial", "fdsvrg"])
+def test_resume_computes_no_snapshot0_and_stays_bit_identical(
+        tmp_path, data, method):
+    """A run resumed at outer 2 of 4 takes its outer-0 snapshot from the
+    checkpoint: it computes two full gradients (one an outer), counts
+    ``snapshot0.carried``, and ends bit for bit where the uninterrupted
+    run does."""
+    from repro.data.block_csr import BlockCSR
+
+    q = 1 if method == "serial" else 4
+    bd = BlockCSR.from_padded(data, balanced(data.dim, q))
+
+    def run(outers, checkpoint=None):
+        cfg = SVRGConfig(eta=0.3, inner_steps=8, outer_iters=outers, seed=13,
+                         batch_size=2)
+        if method == "serial":
+            return run_serial_svrg(None, LOSS, REG, cfg, block_data=bd,
+                                   checkpoint=checkpoint)
+        return run_fdsvrg(None, bd.partition, LOSS, REG, cfg, block_data=bd,
+                          checkpoint=checkpoint)
+
+    ref = run(4)
+    ckdir = str(tmp_path / "ck")
+    run(2, CheckpointPolicy(directory=ckdir, every=2))
+    res, counters = _counted(tmp_path / "trace", lambda: run(
+        4, CheckpointPolicy(directory=ckdir, every=2, resume=True)))
+    assert counters["snapshot0.carried"] == 1
+    assert "snapshot0.computed" not in counters
+    assert counters["full_grad.lanes"] == 2 * _lanes(bd)
+    np.testing.assert_array_equal(np.asarray(res.w), np.asarray(ref.w))
+    assert [h.objective for h in res.history] == \
+        [h.objective for h in ref.history]
+    assert [h.grad_norm for h in res.history] == \
+        [h.grad_norm for h in ref.history]
+    assert res.meter.state_dict() == ref.meter.state_dict()
+
+
+def test_resume_on_other_data_is_rejected(tmp_path, data):
+    """A checkpoint's margins are restored as saved, so a resume on a
+    data set of another row count fails at the first rotation instead
+    of running on."""
+    other = make_sparse_classification(
+        dim=data.dim, num_instances=32, nnz_per_instance=8, seed=3)
+    cfg = SVRGConfig(eta=0.3, inner_steps=8, outer_iters=1, seed=13,
+                     batch_size=2)
+    ckdir = str(tmp_path / "ck")
+    run_serial_svrg(data, LOSS, REG, cfg,
+                    checkpoint=CheckpointPolicy(directory=ckdir))
+    with pytest.raises(ValueError, match="other data"):
+        run_serial_svrg(
+            other, LOSS, REG, SVRGConfig(eta=0.3, inner_steps=8, outer_iters=2,
+                                         seed=13, batch_size=2),
+            checkpoint=CheckpointPolicy(directory=ckdir, resume=True))
 
 
 @pytest.mark.parametrize(

@@ -368,3 +368,70 @@ def test_sharded_driver_pads_odd_dim_on_four_devices_subprocess():
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "OK-4DEV" in proc.stdout
+
+
+_CARRY_PROG = textwrap.dedent(
+    """
+    import os, tempfile
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import obs
+    from repro.api import ExperimentSpec, solve
+    from repro.core import losses
+    from repro.data.synthetic import make_sparse_classification
+    from repro.dist import make_mesh
+
+    mesh = make_mesh((4,), ("model",))
+    dim = {dim}
+    padded = -(-dim // 4) * 4
+    data = make_sparse_classification(dim=dim, num_instances=40, nnz_per_instance=8, seed=1)
+    common = dict(method="fdsvrg_sharded", data=data, mesh=mesh, eta=0.2, inner_steps=10,
+                  outer_iters=2, batch_size=2, reg=losses.l2(1e-3))
+
+    def counted(fn):
+        obs.reset()
+        with jax.profiler.trace(tempfile.mkdtemp()):
+            out = fn()
+        counters = obs.totals()["counters"]
+        obs.reset()
+        return out, counters
+
+    first = solve(ExperimentSpec(seed=4, **common))
+    assert first.final.w.shape == first.final.z.shape == (padded,)
+    assert first.final.s0.shape == (40,)
+    assert first.final.w.sharding == NamedSharding(mesh, P(("model",)))
+    np.testing.assert_array_equal(np.asarray(first.final.w)[:dim], np.asarray(first.w))
+    w = jax.block_until_ready(first.w)
+    copy = jnp.asarray(np.asarray(w))
+    warm, carried = counted(lambda: solve(ExperimentSpec(seed=5, init_w=w, **common)))
+    cold, computed = counted(lambda: solve(ExperimentSpec(seed=5, init_w=copy, **common)))
+    assert carried["snapshot0.carried"] == 1 and "snapshot0.computed" not in carried
+    assert computed["snapshot0.computed"] == 1 and "snapshot0.carried" not in computed
+    assert 3 * carried["full_grad.lanes"] == 2 * computed["full_grad.lanes"]
+    np.testing.assert_array_equal(np.asarray(warm.w), np.asarray(cold.w))
+    assert [h.objective for h in warm.history] == [h.objective for h in cold.history]
+    assert [h.grad_norm for h in warm.history] == [h.grad_norm for h in cold.history]
+    print("OK-CARRY")
+    """
+)
+
+
+@pytest.mark.parametrize("dim", [513, 516])
+def test_sharded_warm_call_carries_the_padded_snapshot_subprocess(dim):
+    """On 4 devices, with d that q divides and d that it does not: the
+    result's final snapshot is in the mesh layout (padded, w and z
+    feature-sharded, s0 replicated), a call from the returned iterate
+    starts from it and computes one full gradient fewer, and it runs bit
+    for bit as the same call from a copy that misses the slot."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src")
+    )
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", _CARRY_PROG.replace("{dim}", str(dim))],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "OK-CARRY" in proc.stdout

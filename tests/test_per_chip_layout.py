@@ -237,9 +237,11 @@ def test_warm_started_solve_reuses_the_layout():
     blocks keep their one mesh placement, a PaddedCSR's layout comes
     from the shared cache, and the compiled halves are reused; from a
     host zeros start and then from returned iterates, the warm-started
-    calls compile nothing."""
+    calls carry the last call's snapshot and compile nothing."""
     _run(
         """
+        import tempfile
+        from repro import obs
         from repro.api import ExperimentSpec, solve
         from repro.api.cache import BLOCK_CACHE
         from repro.core import fdsvrg_shardmap, losses
@@ -266,11 +268,15 @@ def test_warm_started_solve_reuses_the_layout():
                                      init_w=jnp.zeros((data.dim,)), **common))
         placed = bd.on_mesh(MESH, ("model",))
         compiled.clear()
-        second = solve(ExperimentSpec(method="fdsvrg_sharded", data=bd, seed=2,
-                                      init_w=first.w, **common))
-        solve(ExperimentSpec(method="fdsvrg_sharded", data=bd, seed=3,
-                             init_w=second.w, **common))
+        obs.reset()
+        with jax.profiler.trace(tempfile.mkdtemp()):
+            second = solve(ExperimentSpec(method="fdsvrg_sharded", data=bd, seed=2,
+                                          init_w=first.w, **common))
+            solve(ExperimentSpec(method="fdsvrg_sharded", data=bd, seed=3,
+                                 init_w=second.w, **common))
         assert compiled == [], len(compiled)
+        assert obs.totals()["counters"]["snapshot0.carried"] == 2
+        assert "snapshot0.computed" not in obs.totals()["counters"]
         assert built == [] and bd.on_mesh(MESH, ("model",)) is placed
         assert len(bd._on_mesh) == 1
 
