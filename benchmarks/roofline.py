@@ -18,6 +18,7 @@ import subprocess
 import sys
 
 from benchmarks.common import write_bench_json, write_csv
+from repro.launch.roofline import per_program
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 
@@ -26,7 +27,7 @@ def load_results() -> list[dict]:
     out = []
     for path in sorted(glob.glob(os.path.join(os.path.abspath(DRYRUN_DIR), "*.json"))):
         with open(path) as f:
-            out.append(json.load(f))
+            out.extend(per_program(json.load(f)))
     return out
 
 

@@ -1,6 +1,7 @@
 """FD-SVRG (paper Algorithm 1) and serial SVRG (paper Algorithm 2).
 
-Three implementations, one update rule:
+Three drivers, one block update
+(:func:`repro.data.block_csr.block_prox_update`):
 
 * :func:`run_serial_svrg` — Algorithm 2 (Johnson & Zhang), options I/II,
   jitted ``lax.scan`` inner loop.  This is the reference the paper proves
@@ -23,7 +24,10 @@ not here — each implementation supplies only its ``snapshot`` and
 All three run on the block-local layout
 (:class:`repro.data.block_csr.BlockCSR`): each worker's rows carry only
 its own block's entries with local ids, so per-worker gather/scatter work
-is O(nnz_max/q) — no membership masks anywhere on the hot path.  Every
+is O(nnz_max/q) — no membership masks anywhere on the hot path.  Their
+inner steps, and the mesh's in :mod:`repro.core.fdsvrg_shardmap`, end in
+the one block update :func:`repro.data.block_csr.block_prox_update`;
+each keeps only its own reduction of the margins.  Every
 implementation takes ``use_kernels``: ``True`` routes the two hot paths
 through the fused Pallas kernels (:func:`repro.kernels.ops.sparse_margins`
 and :func:`repro.kernels.ops.fused_block_prox_update`, interpret-mode on
@@ -67,7 +71,7 @@ from repro.core.partition import FeaturePartition, balanced
 from repro.dist import COSTS, ClusterModel, Collectives, SimBackend, tree_order_sum
 from repro.data.sparse import PaddedCSR, margins_rows, scatter_grad
 from repro.data.block_csr import (
-    BlockCSR, block_margins, group_margins, group_scatter, local_scatter,
+    BlockCSR, block_margins, block_prox_update, group_margins, group_scatter,
 )
 from repro.kernels import ops
 
@@ -250,7 +254,8 @@ def _inner_epoch(
     The margin of each sampled instance is computed the
     feature-distributed way: q per-block partial dots (local gathers, no
     masks) summed in block order (matching the tree reduce), certifying
-    the decomposition the paper relies on.  The update is the Prox-SVRG
+    the decomposition the paper relies on.  The update, per block
+    :func:`~repro.data.block_csr.block_prox_update`, is the Prox-SVRG
     step ``w <- prox_{eta*g}(w - eta * (grad_vr + z + smooth_grad g))``;
     for the smooth family the prox is the identity and this is exactly
     the classic SVRG step, bit-for-bit.  The prox is elementwise (paper
@@ -293,27 +298,15 @@ def _inner_epoch(
             s_anchor = s0[ids]
         coef = (loss.dvalue(s_m, y) - loss.dvalue(s_anchor, y)) / u
         eta_m = eta * mask
-        new_blocks = []
-        for l in range(q):
-            idx, val = rows[l]
-            w_blk = jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1])
-            z_blk = jax.lax.slice_in_dim(z_data, bounds[l], bounds[l + 1])
-            if use_kernels:
-                k_lam, k_l1, k_l2 = kernel_lams
-                # The fused kernel scatters inside its update.
-                with jax.named_scope("inner/update"):
-                    new_blocks.append(
-                        ops.fused_block_prox_update(
-                            w_blk, idx, val, coef, z_blk, eta_m,
-                            lam=k_lam, lam1=k_l1, lam2=k_l2,
-                        )
-                    )
-            else:
-                with jax.named_scope("inner/scatter"):
-                    g = local_scatter(idx, val, coef, block_dims[l])
-                with jax.named_scope("inner/update"):
-                    g = g + z_blk + reg.smooth_grad(w_blk)
-                    new_blocks.append(reg.prox(w_blk - eta_m * g, eta_m))
+        new_blocks = [
+            block_prox_update(
+                jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1]),
+                rows[l][0], rows[l][1], coef,
+                jax.lax.slice_in_dim(z_data, bounds[l], bounds[l + 1]),
+                eta_m, reg, kernel_lams if use_kernels else None,
+            )
+            for l in range(q)
+        ]
         w_next = jnp.concatenate(new_blocks) if q > 1 else new_blocks[0]
         return w_next, None
 
@@ -749,17 +742,8 @@ def _sim_scatter(groups, coeffs, block_dim):
 def _sim_update(w_block, idx, val, coef, z_block, eta_m, reg_name, lam,
                 use_kernels, lam2=0.0):
     reg = losses_lib.Regularizer(reg_name, lam, lam2)
-    if use_kernels:
-        return ops.fused_block_prox_update(
-            w_block, idx, val, coef, z_block, eta_m,
-            lam=reg.smooth_lam, lam1=reg.prox_l1, lam2=reg.prox_l2,
-        )
-    g = (
-        local_scatter(idx, val, coef, w_block.shape[0])
-        + z_block
-        + reg.smooth_grad(w_block)
-    )
-    return reg.prox(w_block - eta_m * g, eta_m)
+    return block_prox_update(w_block, idx, val, coef, z_block, eta_m, reg,
+                             _kernel_lams(reg, use_kernels))
 
 
 # Lazy per-step worker operations (object-level simulation).  ``m``/``stop``

@@ -31,16 +31,15 @@ interpret mode.  Nothing runs Pallas inside shard_map on a chip: one CPU
 test checks it in interpret mode on a one-device mesh, and ``solve()``
 does not offer it.
 
-Two granularities of compiled step:
-
-* :func:`make_fullgrad` + :func:`make_inner_epoch` — the snapshot and
-  epoch halves :func:`run_fdsvrg_sharded` plugs into the shared
-  outer-loop harness (:func:`repro.core.driver.run_outer_loop`), so the
-  deployable path reports the same :class:`~repro.core.driver.RunResult`
-  schema — objective, same-iterate optimality residual, metered scalars,
-  modeled time — as every other driver, in the data's dtype.
-* :func:`make_outer_iteration` — both phases fused into one jittable
-  call (the AOT shape ``launch/dryrun`` compiles).
+One program pair is compiled per mesh and configuration:
+:func:`make_fullgrad` + :func:`make_inner_epoch`, the snapshot and epoch
+halves :func:`run_fdsvrg_sharded` plugs into the shared outer-loop
+harness (:func:`repro.core.driver.run_outer_loop`), so the deployable
+path reports the same :class:`~repro.core.driver.RunResult` schema —
+objective, same-iterate optimality residual, metered scalars, modeled
+time — as every other driver, in the data's dtype.  ``launch/dryrun``
+lowers the same pair for its AOT shapes.  The epoch's block update is
+the one every driver shares (:func:`repro.data.block_csr.block_prox_update`).
 
 On-device traffic cannot be observed from traced code, so
 :func:`run_fdsvrg_sharded` meters host-side through the backend with the
@@ -68,29 +67,17 @@ from repro.core.driver import (
     resolve_init_w,
     run_outer_loop,
 )
+from repro.core.fdsvrg import _kernel_lams
 from repro.core.partition import FeaturePartition, balanced
 from repro.data.block_csr import (
     BlockCSR,
     RowGroups,
     block_margins,
+    block_prox_update,
     group_margins,
     group_scatter,
-    local_scatter,
 )
 from repro.dist import COSTS, ClusterModel, ShardMapBackend
-from repro.kernels import ops
-
-
-def _opt_residual_blk(reg, eta, w_blk, z_blk):
-    """Block-local optimality residual: the gradient for smooth g, the
-    prox gradient mapping otherwise (the per-block body of
-    repro.core.driver.optimality_norm; callers psum the squares).  Only
-    the fused AOT step reports it — the harness driver evaluates
-    host-side like everyone else."""
-    if reg.is_smooth:
-        return z_blk + reg.grad(w_blk)
-    v_blk = reg.prox(w_blk - eta * (z_blk + reg.smooth_grad(w_blk)), eta)
-    return (w_blk - v_blk) / eta
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,12 +135,6 @@ def _fullgrad_blk(cfg, backend, loss, block, w_blk, groups: RowGroups, labels):
     return z_blk, s0
 
 
-def _slab_groups(bidx, bval) -> RowGroups:
-    """A worker's slab as its one row group (every row in one class)."""
-    ident = jnp.arange(bidx.shape[0], dtype=jnp.int32)
-    return RowGroups((bidx,), (bval,), (ident,), ident)
-
-
 def _groups_spec(groups: RowGroups, axes) -> RowGroups:
     """shard_map specs of a mesh-placed RowGroups: each group's rows
     split over the feature axes, ``rows`` and ``order`` replicated."""
@@ -165,11 +146,13 @@ def _groups_spec(groups: RowGroups, axes) -> RowGroups:
     )
 
 
-def _inner_scan_blk(cfg, backend, loss, reg, block,
+def _inner_scan_blk(cfg, backend, loss, reg,
                     w_blk, z_blk, s0, bidx, bval, labels, samples):
-    """M inner steps on one worker: one u-scalar all-reduce per step; the
-    prox is elementwise on the local block, so the traffic is identical
-    for every regularizer."""
+    """M inner steps on one worker: one u-scalar all-reduce per step,
+    then the shared block update (:func:`block_prox_update`); the prox
+    is elementwise on the local block, so the traffic is identical for
+    every regularizer."""
+    kernel_lams = _kernel_lams(reg, cfg.use_kernels)
 
     def step(w_b, ids):
         with jax.named_scope("inner/gather"):
@@ -181,18 +164,8 @@ def _inner_scan_blk(cfg, backend, loss, reg, block,
         with jax.named_scope("inner/reduce"):
             s_m = backend.device_all_reduce(partial)
         coef = (loss.dvalue(s_m, y) - loss.dvalue(s0[ids], y)) / cfg.batch_size
-        if cfg.use_kernels:
-            with jax.named_scope("inner/update"):
-                w_next = ops.fused_block_prox_update(
-                    w_b, idx, val, coef, z_blk, cfg.eta,
-                    lam=reg.smooth_lam, lam1=reg.prox_l1, lam2=reg.prox_l2,
-                )
-        else:
-            with jax.named_scope("inner/scatter"):
-                g = local_scatter(idx, val, coef, block)
-            with jax.named_scope("inner/update"):
-                g = g + z_blk + reg.smooth_grad(w_b)
-                w_next = reg.prox(w_b - cfg.eta * g, cfg.eta)
+        w_next = block_prox_update(w_b, idx, val, coef, z_blk, cfg.eta, reg,
+                                   kernel_lams)
         return w_next, None
 
     w_blk, _ = jax.lax.scan(step, w_blk, samples)
@@ -243,14 +216,14 @@ def make_inner_epoch(
     block_values, labels, samples) -> w_next`` — the M-step inner scan
     consuming a snapshot produced by :func:`make_fullgrad`, over the
     ``[q*N, B]`` row-stacked slabs."""
-    backend, block = _resolve_backend(mesh, cfg, feature_axes, backend)
+    backend, _ = _resolve_backend(mesh, cfg, feature_axes, backend)
     loss = losses_lib.LOSSES[cfg.loss_name]
     reg = losses_lib.Regularizer(cfg.reg_name, cfg.lam, cfg.lam2)
     axes = backend.feature_axes
 
     def worker(w_blk, z_blk, s0, bidx, bval, labels, samples):
         return _inner_scan_blk(
-            cfg, backend, loss, reg, block,
+            cfg, backend, loss, reg,
             w_blk, z_blk, s0, bidx, bval, labels, samples,
         )
 
@@ -268,66 +241,6 @@ def make_inner_epoch(
         return mapped(w, z, s0, bidx, bval, labels, samples)
 
     return mesh_inner_epoch
-
-
-def make_outer_iteration(
-    mesh: Mesh,
-    cfg: FDSVRGShardedConfig,
-    feature_axes: Sequence[str] = ("data", "model"),
-    backend: ShardMapBackend | None = None,
-):
-    """Build the fused one-outer-iteration function (the AOT shape).
-
-    Signature of the returned fn:
-      (w, block_indices, block_values, labels, samples)
-        -> (w_next, full_grad_norm)
-    with shardings:
-      w:             P(feature_axes)        (feature-distributed, the paper)
-      block_indices: P(feature_axes, None)  int32[q*N, B] local ids
-      block_values:  P(feature_axes, None)  float[q*N, B]
-      labels:        P(None)
-      samples:       P(None, None)          int32[M, u]
-
-    ``full_grad_norm`` is the optimality residual at the *snapshot*
-    iterate (the full-gradient phase computes it for free); the harness
-    driver (:func:`run_fdsvrg_sharded`) reports post-epoch residuals
-    instead, via the split :func:`make_fullgrad` / :func:`make_inner_epoch`
-    pair.  Its full gradient reads the slab as one row group (the AOT
-    shape of uniform rows).  Build the data stack once with
-    ``BlockCSR.from_padded(data, balanced(dim, q)).stacked()``.
-    """
-    backend, block = _resolve_backend(mesh, cfg, feature_axes, backend)
-    loss = losses_lib.LOSSES[cfg.loss_name]
-    reg = losses_lib.Regularizer(cfg.reg_name, cfg.lam, cfg.lam2)
-    axes = backend.feature_axes
-
-    def worker(w_blk, bidx, bval, labels, samples):
-        z_blk, s0 = _fullgrad_blk(
-            cfg, backend, loss, block, w_blk, _slab_groups(bidx, bval), labels
-        )
-        gnorm_sq = jax.lax.psum(
-            jnp.sum(_opt_residual_blk(reg, cfg.eta, w_blk, z_blk) ** 2), axes
-        )
-        w_blk = _inner_scan_blk(
-            cfg, backend, loss, reg, block,
-            w_blk, z_blk, s0, bidx, bval, labels, samples,
-        )
-        return w_blk, gnorm_sq
-
-    spec_w = P(axes)
-    spec_rows = P(axes, None)
-    mapped = backend.shard_map(
-        worker,
-        in_specs=(spec_w, spec_rows, spec_rows, P(None), P(None, None)),
-        out_specs=(spec_w, P()),
-    )
-
-    @jax.jit
-    def outer_iteration(w, block_indices, block_values, labels, samples):
-        w_next, gnorm_sq = mapped(w, block_indices, block_values, labels, samples)
-        return w_next, jnp.sqrt(gnorm_sq)
-
-    return outer_iteration
 
 
 def mesh_partition(dim: int, q: int) -> FeaturePartition:
@@ -476,13 +389,3 @@ def run_fdsvrg_sharded(
     w = jax.device_put(result.w, NamedSharding(mesh, P()))
     return dataclasses.replace(result, w=w[:dim])
 
-
-def input_shardings(mesh: Mesh, feature_axes: Sequence[str] = ("data", "model")):
-    axes = tuple(feature_axes)
-    return (
-        NamedSharding(mesh, P(axes)),
-        NamedSharding(mesh, P(axes, None)),
-        NamedSharding(mesh, P(axes, None)),
-        NamedSharding(mesh, P(None)),
-        NamedSharding(mesh, P(None, None)),
-    )

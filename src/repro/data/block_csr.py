@@ -26,6 +26,8 @@ matches the global layout.
 :func:`local_margins` / :func:`local_scatter` are the two block-local hot
 paths; they are also the numerics contract for the fused Pallas kernels in
 :mod:`repro.kernels` (``sparse_margin``, ``fused_update``).
+:func:`block_prox_update` is the one inner-step block update every FD
+driver calls, jnp or fused kernel.
 
 Each block also carries its rows grouped by stored length
 (:class:`RowGroups`): the full gradient walks every row, so it reads
@@ -618,3 +620,33 @@ def local_scatter(
     flat_idx = indices.reshape(-1)
     flat_val = (values * coeffs[..., None]).reshape(-1)
     return jnp.zeros((block_dim,), dtype=values.dtype).at[flat_idx].add(flat_val)
+
+
+def block_prox_update(w_blk, idx, val, coef, z_blk, eta_m, reg,
+                      kernel_lams: tuple[float, float, float] | None = None):
+    """One block's variance-reduced proximal step (Alg 1 line 11):
+    ``prox_{eta_m g}(w - eta_m * ((sum_i coef_i x^(l)_i + z) + smooth_grad
+    g))`` over the sampled block-LOCAL rows ``(idx, val)``.
+
+    Every FD driver's inner step ends here, after its own reduction of
+    the margins into ``coef``.  ``reg`` is a
+    :class:`repro.core.losses.Regularizer`; the prox is elementwise, so
+    the step is purely block-local.  ``eta_m = 0`` (Option II's masked
+    tail) leaves the block as it is.  Given ``kernel_lams``, the static
+    ``(smooth_lam, prox_l1, prox_l2)`` the fused Pallas kernel bakes in,
+    the step runs as :func:`repro.kernels.ops.fused_block_prox_update`,
+    bit-identical in interpret mode.
+    """
+    if kernel_lams is not None:
+        lam, lam1, lam2 = kernel_lams
+        # The fused kernel scatters inside its update.
+        with jax.named_scope("inner/update"):
+            return ops.fused_block_prox_update(
+                w_blk, idx, val, coef, z_blk, eta_m,
+                lam=lam, lam1=lam1, lam2=lam2,
+            )
+    with jax.named_scope("inner/scatter"):
+        g = local_scatter(idx, val, coef, w_blk.shape[0])
+    with jax.named_scope("inner/update"):
+        g = g + z_blk + reg.smooth_grad(w_blk)
+        return reg.prox(w_blk - eta_m * g, eta_m)

@@ -136,6 +136,25 @@ def from_compiled(compiled, chips: int) -> Roofline:
     )
 
 
+#: Keys a split-program dry-run result holds once per program.
+PER_PROGRAM = ("compile_s", "memory_analysis", "collectives", "roofline")
+
+
+def per_program(result: dict) -> list[dict]:
+    """A dry-run result as one result per compiled program.  The FD-SVRG
+    result keeps one entry per program under each :data:`PER_PROGRAM`
+    key, and each of its parts names its program after the shape; any
+    other result is one program."""
+    rl = result.get("roofline")
+    if not rl or "compute_s" in rl:
+        return [result]
+    return [
+        {**result, "shape": f"{result['shape']}/{name}",
+         **{k: result[k][name] for k in PER_PROGRAM if k in result}}
+        for name in rl
+    ]
+
+
 def model_flops(cfg, shape) -> float:
     """MODEL_FLOPS: 6·N·D for training, 2·N·D per generated/scored token for
     inference (N = active params)."""

@@ -32,12 +32,14 @@ data matrix, one margin tree per batch carrying ``u·k`` scalars.  ``k=1``
 keeps the historical 1-D path untouched (a ``[N, 1]`` label matrix is
 squeezed before any compute), so binary runs are bitwise identical.
 
-Import direction: this module imports the jitted building blocks *from*
-:mod:`repro.core.fdsvrg`; the drivers there import this module lazily
-inside their function bodies.  That keeps the graph acyclic whichever
-module is imported first (``repro.core.__init__`` eagerly imports
-``fdsvrg``, so a module-level import back into ``repro.optim`` from
-there would deadlock the partially-initialized module).
+Import direction: the block update every rule's step ends in
+(:func:`repro.data.block_csr.block_prox_update`, shared with the worker
+simulation and the mesh) sits below both layers.  This module still
+imports the jitted scans *from* :mod:`repro.core.fdsvrg`, and the
+drivers there import this module lazily inside their function bodies:
+``repro.core.__init__`` eagerly imports ``fdsvrg``, so a module-level
+import back into ``repro.optim`` from there would see the
+partially-initialized module.
 """
 
 from __future__ import annotations
@@ -74,7 +76,9 @@ from repro.core.fdsvrg import (
     _lazy_corrections,
     _lazy_inner_epoch,
 )
-from repro.data.block_csr import BlockCSR, local_margins, local_scatter
+from repro.data.block_csr import (
+    BlockCSR, block_prox_update, local_margins, local_scatter,
+)
 from repro.dist import COSTS, Collectives, tree_order_sum
 
 
@@ -507,9 +511,9 @@ def _saga_inner_epoch(
             idx, val = rows[l]
             w_blk = jax.lax.slice_in_dim(w, bounds[l], bounds[l + 1])
             z_blk = jax.lax.slice_in_dim(z, bounds[l], bounds[l + 1])
-            g = local_scatter(idx, val, coef_dir, block_dims[l])
-            g = g + z_blk + reg.smooth_grad(w_blk)
-            new_w.append(reg.prox(w_blk - eta * g, eta))
+            new_w.append(
+                block_prox_update(w_blk, idx, val, coef_dir, z_blk, eta, reg)
+            )
             new_z.append(
                 z_blk + local_scatter(idx, val, coef_tab, block_dims[l])
             )

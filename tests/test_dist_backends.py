@@ -139,13 +139,13 @@ def test_shardmap_backend_guards():
         real.all_reduce([jnp.ones(2)])
     # a backend built on one mesh/axes cannot drive an iteration on another
     # (note jax interns equal meshes, so differ by axis name)
-    from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, make_outer_iteration
+    from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, make_fullgrad
 
     other = make_mesh((1,), ("data",))
     cfg = FDSVRGShardedConfig(dim=8, num_instances=4, nnz_max=2,
                               eta=0.1, inner_steps=2)
     with pytest.raises(ValueError, match="different mesh"):
-        make_outer_iteration(other, cfg, feature_axes=("data",), backend=real)
+        make_fullgrad(other, cfg, feature_axes=("data",), backend=real)
 
 
 # ---------------------------------------------------------------------------

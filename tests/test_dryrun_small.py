@@ -13,7 +13,9 @@ import textwrap
 
 import pytest
 
-from repro.launch.roofline import Roofline, collective_bytes, _shape_bytes
+from repro.launch.roofline import (
+    Roofline, collective_bytes, per_program, _shape_bytes,
+)
 
 
 def test_shape_bytes_parser():
@@ -49,6 +51,25 @@ def test_roofline_dominant_term():
     assert r2.dominant == "memory"
     r3 = Roofline(flops_total=1e12, hbm_bytes_total=1e9, collective_bytes_per_chip=1e13, chips=256)
     assert r3.dominant == "collective"
+
+
+def test_per_program_splits_the_fdsvrg_result():
+    """The FD-SVRG dry run holds one entry per program under each
+    per-compile key; a one-program result passes through as it is."""
+    rl = Roofline(1e12, 1e9, 1e6, 8).as_dict()
+    one = {"arch": "a", "shape": "s", "compile_s": 1.0, "roofline": rl}
+    assert per_program(one) == [one]
+    split = {"arch": "fdsvrg", "shape": "outer", "ok": True,
+             "compile_s": {"p": 1.0, "q": 2.0},
+             "memory_analysis": {"p": {"temp_size_in_bytes": 1}, "q": {}},
+             "collectives": {"p": {"all-reduce": 4}, "q": {}},
+             "roofline": {"p": rl, "q": rl}}
+    parts = per_program(split)
+    assert [r["shape"] for r in parts] == ["outer/p", "outer/q"]
+    assert [r["compile_s"] for r in parts] == [1.0, 2.0]
+    assert parts[0]["memory_analysis"] == {"temp_size_in_bytes": 1}
+    assert parts[0]["collectives"] == {"all-reduce": 4}
+    assert all(r["roofline"] == rl and r["ok"] for r in parts)
 
 
 _SUB = textwrap.dedent("""

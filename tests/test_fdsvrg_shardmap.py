@@ -4,9 +4,10 @@ Single-device mesh in-process; an 8-device feature-sharded run executes in
 a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8 (the
 main test process must keep seeing exactly 1 device).
 
-The shard_map path consumes the block-local stacked layout
-(BlockCSR.stacked / on_mesh): [q*N, B] re-indexed rows split over the
-feature axes, so workers never see global ids.
+The shard_map path consumes the block-local layout placed on the mesh
+(BlockCSR.on_mesh): [q*N, B] re-indexed rows and their row groups split
+over the feature axes, so workers never see global ids.  Its one program
+pair, make_fullgrad + make_inner_epoch, is driven here outer by outer.
 """
 
 import os
@@ -23,8 +24,8 @@ from repro.core import losses
 from repro.core.fdsvrg import SVRGConfig, run_fdsvrg, run_serial_svrg
 from repro.core.fdsvrg_shardmap import (
     FDSVRGShardedConfig,
-    input_shardings,
-    make_outer_iteration,
+    make_fullgrad,
+    make_inner_epoch,
     run_fdsvrg_sharded,
 )
 from repro.core.partition import balanced
@@ -33,8 +34,22 @@ from repro.data.synthetic import make_sparse_classification
 from repro.dist import SimBackend, make_mesh
 
 
-def _stacked(data, q):
-    return BlockCSR.from_padded(data, balanced(data.dim, q)).stacked()
+def _mesh_outer(data, mesh, cfg, q=1):
+    """One outer iteration of the mesh's program pair on the placed
+    layout: ``(w, samples) -> (w_next, z, s0)``."""
+    placed = BlockCSR.from_padded(data, balanced(data.dim, q)).on_mesh(
+        mesh, ("model",)
+    )
+    fullgrad = make_fullgrad(mesh, cfg, feature_axes=("model",))
+    inner_epoch = make_inner_epoch(mesh, cfg, feature_axes=("model",))
+
+    def outer(w, samples):
+        z, s0 = fullgrad(w, placed.groups, placed.labels)
+        w = inner_epoch(w, z, s0, placed.indices, placed.values,
+                        placed.labels, jnp.asarray(samples))
+        return w, z, s0
+
+    return outer
 
 
 def test_shardmap_single_device_matches_serial():
@@ -47,16 +62,14 @@ def test_shardmap_single_device_matches_serial():
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=eta, inner_steps=inner, batch_size=u, lam=lam,
     )
-    step = make_outer_iteration(mesh, cfg, feature_axes=("model",))
-    bidx, bval = _stacked(data, 1)
+    outer = _mesh_outer(data, mesh, cfg)
 
     rng = np.random.default_rng(7)
     w = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
         samples = rng.integers(0, data.num_instances, size=(inner, u)).astype(np.int32)
-        w, gnorm = step(w, bidx, bval, data.labels, jnp.asarray(samples))
+        w, z, s0 = outer(w, samples)
     assert np.all(np.isfinite(np.asarray(w)))
-    assert float(gnorm) >= 0.0
 
     # same sample stream through the serial reference
     rng = np.random.default_rng(7)
@@ -67,16 +80,19 @@ def test_shardmap_single_device_matches_serial():
     block = BlockCSR.from_padded(data, balanced(data.dim, 1))
     w_ref = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
-        z, s0 = _full_grad_blocks(
+        z_ref, s0_ref = _full_grad_blocks(
             block.groups, data.labels, w_ref,
             "logistic", block.block_dims, False,
         )
         samples = rng.integers(0, data.num_instances, size=(inner, u)).astype(np.int32)
         w_ref = _inner_epoch(
-            block.indices, block.values, data.labels, w_ref, z, s0,
+            block.indices, block.values, data.labels, w_ref, z_ref, s0_ref,
             jnp.asarray(samples), eta, jnp.ones(inner, jnp.float32),
             "logistic", "l2", lam, block.block_dims, False,
         )
+    # the last outer's snapshot, then its iterate
+    np.testing.assert_allclose(np.asarray(s0), np.asarray(s0_ref), rtol=2e-4, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(z), np.asarray(z_ref), rtol=2e-4, atol=1e-6)
     np.testing.assert_allclose(np.asarray(w), np.asarray(w_ref), rtol=2e-4, atol=1e-6)
 
 
@@ -89,14 +105,12 @@ def test_shardmap_butterfly_mode_single_device():
         dim=data.dim, num_instances=data.num_instances, nnz_max=data.nnz_max,
         eta=0.1, inner_steps=8, batch_size=1, tree_mode="butterfly",
     )
-    step = make_outer_iteration(mesh, cfg, feature_axes=("model",))
-    bidx, bval = _stacked(data, 1)
     samples = np.zeros((8, 1), dtype=np.int32)
-    w, gnorm = step(
-        jnp.zeros((data.dim,), jnp.float32),
-        bidx, bval, data.labels, jnp.asarray(samples),
+    w, _, s0 = _mesh_outer(data, mesh, cfg)(
+        jnp.zeros((data.dim,), jnp.float32), samples
     )
     assert np.all(np.isfinite(np.asarray(w)))
+    assert np.all(np.isfinite(np.asarray(s0)))
 
 
 def test_shardmap_use_kernels_bit_identical_single_device():
@@ -109,7 +123,6 @@ def test_shardmap_use_kernels_bit_identical_single_device():
     samples = np.random.default_rng(5).integers(
         0, data.num_instances, size=(12, 2)
     ).astype(np.int32)
-    bidx, bval = _stacked(data, 1)
     results = {}
     for use_kernels in (False, True):
         cfg = FDSVRGShardedConfig(
@@ -117,10 +130,10 @@ def test_shardmap_use_kernels_bit_identical_single_device():
             eta=0.2, inner_steps=12, batch_size=2, lam=1e-3,
             use_kernels=use_kernels,
         )
-        step = make_outer_iteration(mesh, cfg, feature_axes=("model",))
+        outer = _mesh_outer(data, mesh, cfg)
         w = jnp.zeros((data.dim,), jnp.float32)
         for _ in range(2):
-            w, gnorm = step(w, bidx, bval, data.labels, jnp.asarray(samples))
+            w, _, _ = outer(w, samples)
         results[use_kernels] = np.asarray(w)
     np.testing.assert_array_equal(results[True], results[False])
 
@@ -199,8 +212,8 @@ def test_sharded_driver_matches_sim_driver_iterates_and_objective():
 
 def test_sharded_driver_gnorm_is_post_epoch_residual():
     """Every record's grad_norm must be the optimality residual at that
-    outer's post-epoch iterate (the fused step fn's own gnorm output is
-    the snapshot residual — one epoch stale for reporting purposes)."""
+    outer's post-epoch iterate, not at the snapshot the epoch started
+    from (one epoch stale for reporting purposes)."""
     from repro.core.fdsvrg import full_gradient, optimality_norm
 
     data = make_sparse_classification(
@@ -256,12 +269,6 @@ def test_sharded_driver_preserves_float64():
         assert np.isfinite(res.history[-1].objective)
 
 
-def test_input_shardings_match_step_arity():
-    mesh = make_mesh((1,), ("model",))
-    shardings = input_shardings(mesh, feature_axes=("model",))
-    assert len(shardings) == 5  # w, block_indices, block_values, labels, samples
-
-
 _SUBPROCESS_PROG = textwrap.dedent(
     """
     import os
@@ -269,7 +276,9 @@ _SUBPROCESS_PROG = textwrap.dedent(
     import jax, numpy as np, jax.numpy as jnp
     from repro.core import losses
     from repro.core.fdsvrg import SVRGConfig, run_serial_svrg
-    from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, make_outer_iteration
+    from repro.core.fdsvrg_shardmap import (
+        FDSVRGShardedConfig, make_fullgrad, make_inner_epoch,
+    )
     from repro.core.partition import balanced
     from repro.data.block_csr import BlockCSR
     from repro.data.synthetic import make_sparse_classification
@@ -282,15 +291,18 @@ _SUBPROCESS_PROG = textwrap.dedent(
     cfg = FDSVRGShardedConfig(dim=data.dim, num_instances=data.num_instances,
                               nnz_max=data.nnz_max, eta=eta, inner_steps=inner,
                               batch_size=u, lam=lam, tree_mode="{mode}")
-    step = make_outer_iteration(mesh, cfg, feature_axes=("model",))
-    bidx, bval = BlockCSR.from_padded(data, balanced(data.dim, 8)).stacked()
+    fullgrad = make_fullgrad(mesh, cfg, feature_axes=("model",))
+    inner_epoch = make_inner_epoch(mesh, cfg, feature_axes=("model",))
+    placed = BlockCSR.from_padded(data, balanced(data.dim, 8)).on_mesh(mesh, ("model",))
     rng = np.random.default_rng(3)
     w = jnp.zeros((data.dim,), jnp.float32)
     all_samples = []
     for t in range(outers):
         s = rng.integers(0, data.num_instances, size=(inner, u)).astype(np.int32)
         all_samples.append(s)
-        w, gnorm = step(w, bidx, bval, data.labels, jnp.asarray(s))
+        z, s0 = fullgrad(w, placed.groups, placed.labels)
+        w = inner_epoch(w, z, s0, placed.indices, placed.values, placed.labels,
+                        jnp.asarray(s))
 
     # serial reference with the same sample stream
     from repro.core.fdsvrg import _full_grad_blocks, _inner_epoch

@@ -220,7 +220,9 @@ def test_prox_shardmap_matches_serial_reference(reg_name, lam, lam2, use_kernels
     """The deployable shard_map worker runs the same prox update: identical
     iterates to the serial reference under a shared sample stream."""
     from repro.core.fdsvrg import _full_grad_blocks, _inner_epoch
-    from repro.core.fdsvrg_shardmap import FDSVRGShardedConfig, make_outer_iteration
+    from repro.core.fdsvrg_shardmap import (
+        FDSVRGShardedConfig, make_fullgrad, make_inner_epoch,
+    )
     from repro.data.block_csr import BlockCSR
 
     data = make_sparse_classification(
@@ -233,9 +235,10 @@ def test_prox_shardmap_matches_serial_reference(reg_name, lam, lam2, use_kernels
         eta=eta, inner_steps=inner, batch_size=u,
         reg_name=reg_name, lam=lam, lam2=lam2, use_kernels=use_kernels,
     )
-    step = make_outer_iteration(mesh, cfg, feature_axes=("model",))
+    fullgrad = make_fullgrad(mesh, cfg, feature_axes=("model",))
+    inner_epoch = make_inner_epoch(mesh, cfg, feature_axes=("model",))
     block = BlockCSR.from_padded(data, balanced(data.dim, 1))
-    bidx, bval = block.stacked()
+    placed = block.on_mesh(mesh, ("model",))
 
     rng = np.random.default_rng(5)
     all_samples = [
@@ -244,8 +247,9 @@ def test_prox_shardmap_matches_serial_reference(reg_name, lam, lam2, use_kernels
     ]
     w = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
-        w, gnorm = step(w, bidx, bval, data.labels, jnp.asarray(all_samples[t]))
-    assert float(gnorm) >= 0.0
+        z, s0 = fullgrad(w, placed.groups, placed.labels)
+        w = inner_epoch(w, z, s0, placed.indices, placed.values, placed.labels,
+                        jnp.asarray(all_samples[t]))
 
     w_ref = jnp.zeros((data.dim,), jnp.float32)
     for t in range(outers):
